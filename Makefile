@@ -8,8 +8,7 @@ export PYTHONPATH
 test:
 	python -m pytest -x -q
 
-# scheduler-policy invariants at a pinned seed (works with real
-# hypothesis or the conftest fallback shim)
+# scheduler-policy invariants at a pinned hypothesis seed
 properties:
 	python -m pytest -q tests/test_scheduler_properties.py \
 	    --hypothesis-seed=0

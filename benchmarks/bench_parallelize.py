@@ -24,6 +24,8 @@ def _worker(tp: int, seq: int, batch: int) -> Dict[str, float]:
     src = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    # placeholder CPU devices only: the chip (if any) belongs to the parent
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
         [sys.executable, "-m", "benchmarks._parallelize_worker",
          str(tp), str(seq), str(batch)],

@@ -19,6 +19,8 @@ import sys
 import time
 import traceback
 
+from repro.core.backend import configure_compile_cache
+
 # Each entry: (short name, module, paper anchor)
 BENCHES = [
     ("table1", "benchmarks.bench_table1", "Table I: model characteristics"),
@@ -46,6 +48,7 @@ def main(argv=None) -> int:
     ap.add_argument("--list", action="store_true")
     ap.add_argument("--out", default="results/bench.csv")
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     if args.list:
         for name, mod, anchor in BENCHES:

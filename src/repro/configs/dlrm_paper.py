@@ -63,8 +63,8 @@ class DLRMConfig:
         return f
 
 
-def _powerlaw_rows(num_tables: int, total_rows: int, alpha: float = 1.05,
-                   min_rows: int = 1000) -> Tuple[int, ...]:
+def powerlaw_rows(num_tables: int, total_rows: int, alpha: float = 1.05,
+                  min_rows: int = 1000) -> Tuple[int, ...]:
     """Deterministic power-law table-size profile (large head, long tail)."""
     weights = [1.0 / (i + 1) ** alpha for i in range(num_tables)]
     s = sum(weights)
@@ -76,7 +76,7 @@ def _powerlaw_rows(num_tables: int, total_rows: int, alpha: float = 1.05,
 PAPER_BASE = DLRMConfig(
     name="dlrm-paper-base",
     num_dense_features=13,
-    table_rows=_powerlaw_rows(64, 1_093_750_000),
+    table_rows=powerlaw_rows(64, 1_093_750_000),
     embed_dim=64,
     avg_lookups_per_table=tuple(1 + (i % 20) for i in range(64)),
     max_lookups_per_table=64,
@@ -88,7 +88,7 @@ PAPER_BASE = DLRMConfig(
 PAPER_COMPLEX = DLRMConfig(
     name="dlrm-paper-complex",
     num_dense_features=13,
-    table_rows=_powerlaw_rows(96, 1_171_875_000),
+    table_rows=powerlaw_rows(96, 1_171_875_000),
     embed_dim=96,
     avg_lookups_per_table=tuple(1 + (i * 7) % 40 for i in range(96)),
     max_lookups_per_table=128,

@@ -2,9 +2,11 @@
 
 The roofline terms in ``launch/hlo_analysis.py`` and the serving perf
 model (``serving/perf_model.py``) both need peak-rate constants.  They
-used to be TPU-v5e literals hardcoded at the roofline call site; this
-module makes them a parameter so a different part (or the paper's
-first-generation accelerator itself) is a spec, not a code edit.
+come from one table keyed by the ``device_kind`` the chip reports
+(``spec_for``), so a different part is a table entry, not a code edit,
+and an unknown part is an error rather than a silent v5e.  The module
+also places JAX's persistent compilation cache for the entry points
+(``configure_compile_cache``).
 
 Transfer-path asymmetry: the paper's deployment measured the
 host->device ingest path sustaining ~0.868 words/cycle while the
@@ -15,7 +17,9 @@ differently instead of assuming a symmetric link.
 """
 from __future__ import annotations
 
+import os
 from dataclasses import dataclass
+from pathlib import Path
 from typing import Dict
 
 # Measured ingest/readback rates (words/cycle) from the accelerator
@@ -59,8 +63,9 @@ class BackendSpec:
         return self.peak_flops_bf16 / self.peak_flops(precision)
 
 
-# TPU v5e — the numbers previously hardcoded in hlo_analysis.py, plus a
-# PCIe-class host link with the measured readback asymmetry applied.
+# TPU v5e (Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+# 394 TOP/s int8, 819 GB/s HBM), plus a PCIe-class host link with the
+# readback asymmetry above applied.
 TPU_V5E = BackendSpec(
     name="tpu-v5e",
     peak_flops_bf16=197e12,
@@ -71,6 +76,47 @@ TPU_V5E = BackendSpec(
     d2h_bw=32e9 * D2H_H2D_RATIO,
 )
 
-DEFAULT_BACKEND = TPU_V5E
+# Keyed by ``device.device_kind`` exactly as the chip reports it.
+BACKENDS: Dict[str, BackendSpec] = {"TPU v5 lite": TPU_V5E}
 
-BACKENDS: Dict[str, BackendSpec] = {TPU_V5E.name: TPU_V5E}
+
+def spec_for(device=None) -> BackendSpec:
+    """The peak envelope of ``device`` (default: the first JAX device).
+
+    An accelerator whose ``device_kind`` is not in ``BACKENDS`` raises —
+    borrowing another part's peaks would misprice every knob the perf
+    model sets. The CPU backend maps to v5e so tests and CPU rehearsals
+    price the chip the program is written for."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    if device.platform == "cpu":
+        return TPU_V5E
+    try:
+        return BACKENDS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no BackendSpec for device_kind {device.device_kind!r} "
+            f"({device.platform}); known: {sorted(BACKENDS)}") from None
+
+
+def compile_cache_dir() -> str:
+    """Where JAX's persistent compilation cache lives: the directory
+    ``JAX_COMPILATION_CACHE_DIR`` names (JAX reads that variable itself),
+    else the fixed ``<checkout>/.jax_cache`` — a fixed path, because the
+    path is part of what a later process must find again."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    return str(Path(__file__).resolve().parents[3] / ".jax_cache")
+
+
+def configure_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at ``compile_cache_dir()``
+    for the program's entry points. Sets nothing when
+    ``JAX_COMPILATION_CACHE_DIR`` is set. Returns the directory in use."""
+    path = compile_cache_dir()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        import jax
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path

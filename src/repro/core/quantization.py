@@ -132,8 +132,7 @@ def dense_w8a8(x: jax.Array, qw: Dict[str, jax.Array]) -> jax.Array:
     if jax.default_backend() == "tpu" and x.ndim >= 2:
         from repro.kernels.w8a8.matmul import w8a8_matmul
         K, N = q8.shape
-        y = w8a8_matmul(xq.reshape(-1, K), q8, xs.reshape(-1), w_scale,
-                        interpret=False)
+        y = w8a8_matmul(xq.reshape(-1, K), q8, xs.reshape(-1), w_scale)
         return y.reshape(x.shape[:-1] + (N,)).astype(x.dtype)
     acc = jnp.einsum("...k,kn->...n", xq.astype(jnp.int32),
                      q8.astype(jnp.int32))

@@ -1,3 +1,18 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels for the paper's hot spots (SLS, w8a8 FC, attention).
+
+Every kernel entry point takes ``interpret=None`` and resolves it here:
+the Pallas interpreter runs only on the CPU backend (tests), so a call
+on the chip always runs the compiled kernel.
+"""
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+
+
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """The caller's explicit choice, else True only on the CPU backend."""
+    if interpret is None:
+        return jax.default_backend() == "cpu"
+    return interpret

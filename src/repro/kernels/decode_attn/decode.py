@@ -12,11 +12,14 @@ past ``pos`` are masked, and their work is skipped).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -64,7 +67,7 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def flash_decode(q, k, v, pos, *, bs: int = 512, softcap: float = 0.0,
-                 interpret: bool = True):
+                 interpret: Optional[bool] = None):
     """q (B,H,hd); k,v (B,S,K,hd); pos () int32 -> (B,H,hd) f32."""
     B, H, hd = q.shape
     S, K = k.shape[1], k.shape[2]
@@ -92,7 +95,7 @@ def flash_decode(q, k, v, pos, *, bs: int = 512, softcap: float = 0.0,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, K, G, hd), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(jnp.asarray(pos, jnp.int32).reshape(1), qg, k, v)
     return out.reshape(B, H, hd)
 
@@ -146,7 +149,7 @@ def _decode_int8_kernel(pos_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
 
 
 def flash_decode_int8(q, kq, k_scale, vq, v_scale, pos, *, bs: int = 512,
-                      softcap: float = 0.0, interpret: bool = True):
+                      softcap: float = 0.0, interpret: Optional[bool] = None):
     """q (B,H,hd); kq,vq (B,S,K,hd) int8; *_scale (B,S,K) fp16;
     pos () int32 -> (B,H,hd) f32."""
     B, H, hd = q.shape
@@ -177,6 +180,6 @@ def flash_decode_int8(q, kq, k_scale, vq, v_scale, pos, *, bs: int = 512,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, K, G, hd), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(jnp.asarray(pos, jnp.int32).reshape(1), qg, kq, k_scale, vq, v_scale)
     return out.reshape(B, H, hd)

@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -14,7 +15,7 @@ from repro.kernels.decode_attn.ref import decode_attn_ref
 @functools.partial(jax.jit,
                    static_argnames=("bs", "softcap", "interpret"))
 def decode_attn(q, k, v, pos, *, bs: int = 512, softcap: float = 0.0,
-                interpret: bool = True):
+                interpret: Optional[bool] = None):
     return flash_decode(q, k, v, pos, bs=bs, softcap=softcap,
                         interpret=interpret)
 
@@ -59,7 +60,7 @@ from repro.kernels.decode_attn.ref import decode_attn_int8_ref
 
 @functools.partial(jax.jit, static_argnames=("bs", "softcap", "interpret"))
 def decode_attn_int8(q, kq, k_scale, vq, v_scale, pos, *, bs: int = 512,
-                     softcap: float = 0.0, interpret: bool = True):
+                     softcap: float = 0.0, interpret: Optional[bool] = None):
     return flash_decode_int8(q, kq, k_scale, vq, v_scale, pos, bs=bs,
                              softcap=softcap, interpret=interpret)
 

@@ -9,9 +9,12 @@ just Q + K + V + O.
 TPU mapping: grid (batch, kv-head, q-block, kv-block), innermost kv axis
 sequential so the online-softmax state (m, l, acc) lives in VMEM scratch
 per (G*Bq, hd) tile; K/V stream HBM->VMEM in (Bk, hd) blocks; the (G*Bq,
-Bk) logits tile feeds the MXU twice (QK^T and PV). Causal/local masks are
-resolved from block indices — fully-masked kv blocks are skipped (the
-paper's "only the used prefix is ever read", T6, applied to the causal
+Bk) logits tile feeds the MXU twice (QK^T and PV). The wrapper lays Q out
+head-major (B, K, G, S, hd) and K/V as (B, K, T, hd), so every block's last
+two dims are (rows, hd) — the (8, 128) tiling the chip's compiler requires
+(a (1, hd) slice out of a K-head axis is refused for K > 1). Causal/local
+masks are resolved from block indices — fully-masked kv blocks are skipped
+(the paper's "only the used prefix is ever read", T6, applied to the causal
 frontier).
 
 Supports GQA/MQA (G = H/K query heads per kv head), causal and
@@ -21,11 +24,14 @@ batches (scalar-prefetched per-row lengths).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -55,31 +61,31 @@ def _flash_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(live)
     def _block():
-        q = q_ref[0, :, 0, :, :].astype(jnp.float32)       # (Bq, G, hd)
-        G, hd = q.shape[1], q.shape[2]
-        q2 = q.reshape(bq * G, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)          # (Bk, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32)                # (G, Bq, hd)
+        G, hd = q.shape[0], q.shape[2]
+        rows = G * bq
+        q2 = q.reshape(rows, hd)                           # row = g*Bq + i
+        k = k_ref[0, 0].astype(jnp.float32)                # (Bk, hd)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q2, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        s = s * scale                                      # (Bq*G, Bk)
+        s = s * scale                                      # (G*Bq, Bk)
         if softcap:
             s = softcap * jnp.tanh(s / softcap)
-        qpos = q_start + jax.lax.iota(jnp.int32, bq)       # (Bq,)
-        kpos = k_start + jax.lax.iota(jnp.int32, bk)       # (Bk,)
-        mask = jnp.ones((bq, bk), jnp.bool_)
+        r = jax.lax.broadcasted_iota(jnp.int32, (rows, bk), 0)
+        qpos = q_start + (r if G == 1 else jax.lax.rem(r, bq))
+        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (rows, bk), 1)
+        mask = kpos < lens_ref[b]                          # padded tail
         if causal:
-            mask &= qpos[:, None] >= kpos[None, :]
+            mask &= qpos >= kpos
         if window > 0:
-            mask &= qpos[:, None] - kpos[None, :] < window
-        mask &= kpos[None, :] < lens_ref[b]                # padded tail
-        mask2 = jnp.repeat(mask, G, axis=0)                # (Bq*G, Bk)
-        s = jnp.where(mask2, s, NEG_INF)
-        m_prev = m_ref[...]                                # (Bq*G, 1)
+            mask &= qpos - kpos < window
+        s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_ref[...]                                # (G*Bq, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
-        p = jnp.where(mask2, p, 0.0)
+        p = jnp.where(mask, p, 0.0)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
@@ -89,14 +95,13 @@ def _flash_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(ki == nk - 1)
     def _finalize():
         o = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :, :] = o.reshape(bq, o_ref.shape[3], o_ref.shape[4]) \
-            .astype(o_ref.dtype)
+        o_ref[0, 0] = o.reshape(o_ref.shape[2:]).astype(o_ref.dtype)
 
 
 def flash_attention(q, k, v, lens=None, *, causal: bool = True,
                     window: int = 0, softcap: float = 0.0,
                     bq: int = 512, bk: int = 512,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """q (B,S,H,hd); k,v (B,T,K,hd); lens (B,) valid kv length (default T).
 
     Returns (B,S,H,hd) in q.dtype. S % bq == 0 and T % bk == 0 required
@@ -111,7 +116,9 @@ def flash_attention(q, k, v, lens=None, *, causal: bool = True,
     nq, nk = S // bq, T // bk
     if lens is None:
         lens = jnp.full((B,), T, jnp.int32)
-    qg = q.reshape(B, S, K, G, hd)
+    qg = q.reshape(B, S, K, G, hd).transpose(0, 2, 3, 1, 4)   # (B,K,G,S,hd)
+    kt = k.transpose(0, 2, 1, 3)                               # (B,K,T,hd)
+    vt = v.transpose(0, 2, 1, 3)
     out = pl.pallas_call(
         functools.partial(_flash_kernel, bq=bq, bk=bk, nk=nk, causal=causal,
                           window=window, softcap=softcap,
@@ -120,22 +127,22 @@ def flash_attention(q, k, v, lens=None, *, causal: bool = True,
             num_scalar_prefetch=1,
             grid=(B, K, nq, nk),
             in_specs=[
-                pl.BlockSpec((1, bq, 1, G, hd),
-                             lambda b, h, qi, ki, lens: (b, qi, h, 0, 0)),
-                pl.BlockSpec((1, bk, 1, hd),
-                             lambda b, h, qi, ki, lens: (b, ki, h, 0)),
-                pl.BlockSpec((1, bk, 1, hd),
-                             lambda b, h, qi, ki, lens: (b, ki, h, 0)),
+                pl.BlockSpec((1, 1, G, bq, hd),
+                             lambda b, h, qi, ki, lens: (b, h, 0, qi, 0)),
+                pl.BlockSpec((1, 1, bk, hd),
+                             lambda b, h, qi, ki, lens: (b, h, ki, 0)),
+                pl.BlockSpec((1, 1, bk, hd),
+                             lambda b, h, qi, ki, lens: (b, h, ki, 0)),
             ],
-            out_specs=pl.BlockSpec((1, bq, 1, G, hd),
-                                   lambda b, h, qi, ki, lens: (b, qi, h, 0, 0)),
+            out_specs=pl.BlockSpec((1, 1, G, bq, hd),
+                                   lambda b, h, qi, ki, lens: (b, h, 0, qi, 0)),
             scratch_shapes=[
-                pltpu.VMEM((bq * G, 1), jnp.float32),
-                pltpu.VMEM((bq * G, 1), jnp.float32),
-                pltpu.VMEM((bq * G, hd), jnp.float32),
+                pltpu.VMEM((G * bq, 1), jnp.float32),
+                pltpu.VMEM((G * bq, 1), jnp.float32),
+                pltpu.VMEM((G * bq, hd), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, S, K, G, hd), q.dtype),
-        interpret=interpret,
-    )(jnp.asarray(lens, jnp.int32), qg, k, v)
-    return out.reshape(B, S, H, hd)
+        out_shape=jax.ShapeDtypeStruct((B, K, G, S, hd), q.dtype),
+        interpret=resolve_interpret(interpret),
+    )(jnp.asarray(lens, jnp.int32), qg, kt, vt)
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, S, H, hd)

@@ -6,6 +6,7 @@ extra queries sliced off) so arbitrary sequence lengths work.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -15,14 +16,20 @@ from repro.kernels.flash_attn.flash import flash_attention
 from repro.kernels.flash_attn.ref import flash_attention_ref
 
 
+MAX_Q_ROWS = 512
+
+
 @functools.partial(jax.jit, static_argnames=("causal", "window", "softcap",
                                              "bq", "bk", "interpret"))
 def flash_attn(q, k, v, lens=None, *, causal: bool = True, window: int = 0,
                softcap: float = 0.0, bq: int = 512, bk: int = 512,
-               interpret: bool = True):
+               interpret: Optional[bool] = None):
     B, S = q.shape[:2]
     T = k.shape[1]
-    bq_ = min(bq, S) if S % min(bq, S) == 0 else min(bq, S)
+    # a q block holds G = H/K heads: cap its G*bq logit rows so the tile
+    # fits VMEM (MQA at bq=512 would need 46 MB of a 16 MB scoped limit)
+    G = q.shape[2] // k.shape[2]
+    bq_ = min(bq, S, max(8, MAX_Q_ROWS // G // 8 * 8))
     Sp = -(-S // bq_) * bq_ if S % bq_ else S
     bk_ = min(bk, T)
     Tp = -(-T // bk_) * bk_ if T % bk_ else T

@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -13,18 +14,20 @@ from repro.kernels.sls.sls import sls_int4_pallas, sls_int8_pallas, sls_pallas
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def sls(table, indices, lengths, *, interpret: bool = True):
+def sls(table, indices, lengths, *, interpret: Optional[bool] = None):
     return sls_pallas(table, indices, lengths, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def sls_int8(q, scale, bias, indices, lengths, *, interpret: bool = True):
+def sls_int8(q, scale, bias, indices, lengths, *,
+             interpret: Optional[bool] = None):
     return sls_int8_pallas(q, scale, bias, indices, lengths,
                            interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def sls_int4(q4, scale, bias, indices, lengths, *, interpret: bool = True):
+def sls_int4(q4, scale, bias, indices, lengths, *,
+             interpret: Optional[bool] = None):
     return sls_int4_pallas(q4, scale, bias, indices, lengths,
                            interpret=interpret)
 

@@ -11,11 +11,14 @@ inner grid dimension).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 
 def _sls_fp_kernel(idx_ref, len_ref, table_ref, out_ref, *, L: int):
@@ -75,7 +78,7 @@ def _scalar_spec(L):
     return pl.BlockSpec((1, 1), lambda b, l, idx, lens: (idx[b * L + l], 0))
 
 
-def sls_pallas(table, indices, lengths, *, interpret: bool = True):
+def sls_pallas(table, indices, lengths, *, interpret: Optional[bool] = None):
     """Float table. indices (NB, L), lengths (NB,) -> (NB, D) f32."""
     NB, L = indices.shape
     R, D = table.shape
@@ -90,12 +93,12 @@ def sls_pallas(table, indices, lengths, *, interpret: bool = True):
             out_specs=pl.BlockSpec((1, D), lambda b, l, idx, lens: (b, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((NB, D), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(indices.reshape(-1), lengths, table)
 
 
 def sls_int8_pallas(q, scale, bias, indices, lengths, *,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """Row-wise int8 table with fused dequant. q (R,D) uint8; scale/bias (R,)."""
     NB, L = indices.shape
     R, D = q.shape
@@ -115,12 +118,12 @@ def sls_int8_pallas(q, scale, bias, indices, lengths, *,
             out_specs=pl.BlockSpec((1, D), lambda b, l, idx, lens: (b, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((NB, D), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(indices.reshape(-1), lengths, q, s2, b2)
 
 
 def sls_int4_pallas(q4, scale, bias, indices, lengths, *,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """Packed int4 table (R, D//2) uint8 with fused unpack+dequant."""
     NB, L = indices.shape
     R, Dh = q4.shape
@@ -140,5 +143,5 @@ def sls_int4_pallas(q4, scale, bias, indices, lengths, *,
             out_specs=pl.BlockSpec((1, 2 * Dh), lambda b, l, idx, lens: (b, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((NB, 2 * Dh), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(indices.reshape(-1), lengths, q4, s2, b2)

@@ -17,11 +17,14 @@ casing at the call site.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 
 def _w8a8_kernel(x_ref, w_ref, xs_ref, ws_ref, out_ref, acc_ref, *, nk: int):
@@ -51,7 +54,7 @@ def _pad_dim(a, axis: int, to: int):
 
 
 def w8a8_matmul(xq, wq, x_scale, w_scale, *, bm: int = 128, bn: int = 128,
-                bk: int = 128, interpret: bool = True):
+                bk: int = 128, interpret: Optional[bool] = None):
     """xq (M,K) int8, wq (K,N) int8, x_scale scalar or (M,)/(M,1) f32
     (per-row activation scales), w_scale (N,) f32 -> (M,N) f32."""
     M, K = xq.shape
@@ -84,6 +87,6 @@ def w8a8_matmul(xq, wq, x_scale, w_scale, *, bm: int = 128, bn: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xq, wq, xs, ws)
     return out[:M, :N]

@@ -13,17 +13,10 @@ from repro.kernels.w8a8.ref import w8a8_ref
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _w8a8_jit(xq, wq, x_scale, w_scale, *, interpret: bool):
-    return w8a8_matmul(xq, wq, x_scale, w_scale, interpret=interpret)
-
-
 def w8a8(xq, wq, x_scale, w_scale, *, interpret: Optional[bool] = None):
-    """Dequantizing int8 matmul; ``interpret`` follows the backend like the
-    other kernels (compiled on TPU, interpreter elsewhere) unless the
-    caller pins it."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return _w8a8_jit(xq, wq, x_scale, w_scale, interpret=interpret)
+    """Dequantizing int8 matmul (compiled on the chip, interpreted on the
+    CPU backend unless the caller pins ``interpret``)."""
+    return w8a8_matmul(xq, wq, x_scale, w_scale, interpret=interpret)
 
 
 def _mk(M, K, N, row_scale=False):

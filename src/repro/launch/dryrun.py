@@ -200,8 +200,11 @@ def main():
                 cmd += ["--dump-hlo", args.dump_hlo]
             if args.preset:
                 cmd += ["--preset", args.preset]
+            # each cell analyses placeholder CPU devices: a child must
+            # never reach for a chip this process may hold
             procs.append((subprocess.Popen(
-                cmd, stdout=subprocess.PIPE, stderr=subprocess.STDOUT),
+                cmd, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
+                env=dict(os.environ, JAX_PLATFORMS="cpu")),
                 f"{arch} x {shape} x {m}"))
         while procs:
             drain(block=True)

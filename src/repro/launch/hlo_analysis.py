@@ -562,20 +562,21 @@ def analyze(text: str) -> HloSummary:
 
 
 # --------------------------------------------------------------------------
-# Roofline terms — peak rates come from a BackendSpec (default TPU v5e)
+# Roofline terms — peak rates come from a BackendSpec (default: TPU v5e,
+# the part the dry-run compiles for)
 # --------------------------------------------------------------------------
-from repro.core.backend import DEFAULT_BACKEND, BackendSpec  # noqa: E402
+from repro.core.backend import TPU_V5E, BackendSpec  # noqa: E402
 
 # Back-compat aliases: these used to be hardcoded literals here and are
 # imported by the roofline/fig7/table2 benches.
-PEAK_FLOPS_BF16 = DEFAULT_BACKEND.peak_flops_bf16   # per chip
-HBM_BW = DEFAULT_BACKEND.hbm_bw                     # per chip
-ICI_BW = DEFAULT_BACKEND.ici_bw                     # per link
+PEAK_FLOPS_BF16 = TPU_V5E.peak_flops_bf16   # per chip
+HBM_BW = TPU_V5E.hbm_bw                     # per chip
+ICI_BW = TPU_V5E.ici_bw                     # per link
 
 
 def roofline_terms(summary: HloSummary, *,
                    flops_override: Optional[float] = None,
-                   spec: BackendSpec = DEFAULT_BACKEND) -> Dict[str, float]:
+                   spec: BackendSpec = TPU_V5E) -> Dict[str, float]:
     """All terms are seconds (per-device program => per-chip time)."""
     flops = flops_override if flops_override is not None else summary.dot_flops
     return {

@@ -3,8 +3,14 @@ this module never touches jax device state."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from repro.core.jax_compat import make_mesh
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto`` (the sharding
+    rules place arrays with ``with_sharding_constraint``, not explicit
+    axis types)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -52,6 +52,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, reduce_for_smoke
+from repro.core.backend import configure_compile_cache
 from repro.models import model as model_mod
 from repro.serving.engine import InferenceEngine, Request, make_replicas
 from repro.serving.router import ReplicaRouter
@@ -602,6 +603,7 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true", default=True)
     ap.add_argument("--full-config", dest="smoke", action="store_false")
     args = ap.parse_args(argv)
+    configure_compile_cache()
     if args.elastic_smoke:
         return elastic_smoke()
     if args.arch == "dlrm":

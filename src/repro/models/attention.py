@@ -13,7 +13,6 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.core.quantization import dense_w8a8, is_quantized_dense
 from repro.models.common import (apply_mrope, apply_rope, mk_param, softcap)
-from repro.core.jax_compat import shard_map
 from repro.sharding.rules import (current_ctx, logical_to_spec, Logical,
                                   mesh_axis_names, mesh_axis_size, shard)
 
@@ -206,8 +205,7 @@ def full_attention(p, x, cfg: ModelConfig, kind: str, positions,
             else None
         o = flash_attn(q, k, v, lens, causal=True,
                        window=cfg.window_size if kind == "local" else 0,
-                       softcap=cfg.attn_logit_softcap or 0.0,
-                       interpret=jax.default_backend() != "tpu")
+                       softcap=cfg.attn_logit_softcap or 0.0)
         o = shard(o, "batch", "seq", "heads", None)
         return _out_proj(p, o), (k, v)
 
@@ -601,7 +599,7 @@ def _decode_seq_sharded(q, k_new, v_new, cache, pos, cfg: ModelConfig):
         o = jnp.swapaxes(o, 1, 3).reshape(B, S1, K * G, hd)
         return o.astype(q.dtype), ck, cv
 
-    o, ck, cv = shard_map(
+    o, ck, cv = jax.shard_map(
         body, mesh=mesh,
         in_specs=(q_spec, qkv_spec, qkv_spec, cache_spec, cache_spec, P()),
         out_specs=(q_spec, cache_spec, cache_spec),

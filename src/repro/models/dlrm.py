@@ -18,7 +18,6 @@ import numpy as np
 from repro.configs.dlrm_paper import DLRMConfig
 from repro.core.partitioner import TableAssignment, partition_tables
 from repro.core.quantization import quantize_rows
-from repro.core.jax_compat import shard_map
 from repro.sharding.rules import (Logical, current_ctx, logical_to_spec,
                                   mesh_axis_names, mesh_axis_size)
 
@@ -130,7 +129,7 @@ def sls_forward(params, cfg: DLRMConfig, assignment: TableAssignment,
                          else spec("table_rows")) for k in slab}
     else:
         slab_spec = spec("table_rows", None)
-    pooled = shard_map(
+    pooled = jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(slab_spec, spec(None, None, None), spec(None, None)),
         out_specs=spec(None, None, None), check_vma=False,

@@ -22,7 +22,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.models.common import activation_fn, mk_param
-from repro.core.jax_compat import shard_map
 from repro.sharding.rules import (Logical, current_ctx, logical_to_spec,
                                   mesh_axis_names, mesh_axis_size)
 
@@ -190,7 +189,7 @@ def moe_apply(p, x, cfg: ModelConfig):
         x_sp = spec(x.shape, "batch", None, None)
         if extra:
             x_sp = P(x_sp[0] if len(x_sp) else None, extra)
-        out, aux = shard_map(
+        out, aux = jax.shard_map(
             body, mesh=mesh,
             in_specs=(x_sp, spec(p["router"].shape, None, None),
                       spec(p["wg"].shape, "experts", None, "expert_mlp"),

@@ -137,7 +137,8 @@ class InferenceEngine:
                  prefix_cache: Optional[int] = None,
                  page_host: bool = False,
                  page_victim: str = "lru",
-                 migrate_min_tokens: Optional[int] = None):
+                 migrate_min_tokens: Optional[int] = None,
+                 device: Optional[jax.Device] = None):
         if page_victim not in ("lru", "remaining"):
             raise ValueError(f"page_victim must be 'lru' or 'remaining', "
                              f"got {page_victim!r}")
@@ -160,6 +161,10 @@ class InferenceEngine:
             self.run_params = quantized_params.params
         else:
             self.run_params = params
+        # pinned replica: weights and caches live on ``device`` (a copy per
+        # replica); None keeps JAX's default placement
+        if device is not None:
+            self.run_params = jax.device_put(self.run_params, device)
         self.max_len = max_len
         self.batch_slots = batch_slots
         self.buckets = tuple(b for b in prefill_buckets if b <= max_len)
@@ -171,7 +176,7 @@ class InferenceEngine:
         # the auto prefill chunk, the estimator's cold-start priors, and
         # the router's per-precision scale-up seed
         self.perf_model = (perf_model if perf_model is not None
-                           else PerfModel.for_params(params))
+                           else PerfModel.for_params(params, device=device))
         if prefill_chunk == "auto":
             # self-tuning knob: the chunk at the model's per-bucket
             # efficiency knee instead of a hand-set literal (chunked
@@ -212,7 +217,9 @@ class InferenceEngine:
                                    service_ms_fallback=service_ms_fallback,
                                    perf_model=self.perf_model)
 
-        self.caches = model_mod.init_caches(cfg, batch_slots, max_len)
+        with jax.default_device(device):     # None: JAX's default
+            self.caches = jax.device_put(
+                model_mod.init_caches(cfg, batch_slots, max_len), device)
         self._batch_axes = _cache_batch_axes(cfg, max_len)
         self._seq_axes = _cache_seq_axes(cfg, batch_slots, max_len)
         # per-slot sequence state: the free/active/prefilling partition,
@@ -930,6 +937,7 @@ class InferenceEngine:
 def make_replicas(cfg: ModelConfig, params, n: int,
                   precisions: Optional[Sequence[str]] = None,
                   quant_budget: float = 0.05,
+                  devices: Optional[Sequence[jax.Device]] = None,
                   **engine_kw) -> List[InferenceEngine]:
     """N LM engine replicas sharing one set of weights (the paper's
     data-parallel deployment: same model on each card, distinct KV caches
@@ -939,11 +947,20 @@ def make_replicas(cfg: ModelConfig, params, n: int,
     (``"fp32"`` / ``"w8a8"``) — the heterogeneous-fleet deployment where
     bulk traffic flows to int8 cards while accuracy-sensitive traffic
     pins to fp32 (the router's mixed-precision policy). The quantized
-    weights are built ONCE and shared by every w8a8 replica."""
+    weights are built ONCE and shared by every w8a8 replica.
+
+    ``devices`` pins replica *i* to ``devices[i]`` with its own copy of
+    the weights and its own caches — one card per replica, as deployed.
+    Without it every replica shares the default device."""
     if precisions is None:
         precisions = ["fp32"] * n
     if len(precisions) != n:
         raise ValueError(f"precisions has {len(precisions)} entries for "
+                         f"{n} replicas")
+    if devices is None:
+        devices = [None] * n
+    if len(devices) != n:
+        raise ValueError(f"devices has {len(devices)} entries for "
                          f"{n} replicas")
     qp = None
     if any(p == "w8a8" for p in precisions):
@@ -951,5 +968,5 @@ def make_replicas(cfg: ModelConfig, params, n: int,
         qp = build_quantized_params(cfg, params, budget=quant_budget)
     return [InferenceEngine(cfg, params, precision=p,
                             quantized_params=qp if p == "w8a8" else None,
-                            **engine_kw)
-            for p in precisions]
+                            device=d, **engine_kw)
+            for p, d in zip(precisions, devices)]

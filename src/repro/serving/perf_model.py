@@ -43,7 +43,7 @@ import json
 import math
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
-from repro.core.backend import DEFAULT_BACKEND, BackendSpec
+from repro.core.backend import BackendSpec, spec_for
 from repro.core.bucketing import DEFAULT_BUCKETS
 from repro.core.transfer import TransferStats
 from repro.serving.telemetry import percentile
@@ -75,11 +75,11 @@ def _median(vals: Sequence[float]) -> float:
 class PerfModel:
     """Analytic + measured per-bucket dispatch-cost model for one model
     architecture (``flops_per_token`` of dense forward work) on one
-    backend spec."""
+    backend spec (default: the spec of the first JAX device)."""
 
     def __init__(self, flops_per_token: float = 1.0, *,
-                 spec: BackendSpec = DEFAULT_BACKEND):
-        self.spec = spec
+                 spec: Optional[BackendSpec] = None):
+        self.spec = spec if spec is not None else spec_for()
         self.flops_per_token = float(flops_per_token)
         # (stage, bucket, batch, precision) -> measured dispatch seconds
         self._samples: Dict[Tuple[str, int, int, str], List[float]] = {}
@@ -93,14 +93,14 @@ class PerfModel:
         self._precision_scale: Dict[str, float] = {}
 
     @classmethod
-    def for_params(cls, params, *,
-                   spec: BackendSpec = DEFAULT_BACKEND) -> "PerfModel":
+    def for_params(cls, params, *, device=None) -> "PerfModel":
         """Model sized from a parameter pytree: dense forward FLOPs per
-        token ~= 2 x weight count (every weight is one multiply-add)."""
+        token ~= 2 x weight count (every weight is one multiply-add),
+        priced on ``device``'s spec (default: the first JAX device)."""
         import jax
         n = sum(int(getattr(leaf, "size", 0))
                 for leaf in jax.tree.leaves(params))
-        return cls(2.0 * max(n, 1), spec=spec)
+        return cls(2.0 * max(n, 1), spec=spec_for(device))
 
     # ---- calibration -----------------------------------------------------
     def observe(self, stage: str, *, bucket: int, batch: int = 1,

@@ -17,7 +17,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.models.common import VOCAB_PAD_MULT, round_up, softcap
-from repro.core.jax_compat import shard_map
 from repro.sharding.rules import (Logical, current_ctx, logical_to_spec,
                                   mesh_axis_names, mesh_axis_size)
 
@@ -58,8 +57,9 @@ def embed_lookup(table, tokens, cfg: ModelConfig):
         tok_spec = _spec(ctx, ("batch", None), tokens.shape)
         out_spec = _spec(ctx, ("batch", None, None),
                          tokens.shape + (table.shape[1],))
-        out = shard_map(body, mesh=ctx.mesh, in_specs=(t_spec, tok_spec),
-                            out_specs=out_spec, check_vma=False)(table, tokens)
+        out = jax.shard_map(
+            body, mesh=ctx.mesh, in_specs=(t_spec, tok_spec),
+            out_specs=out_spec, check_vma=False)(table, tokens)
     if cfg.embedding_multiplier:
         out = (out.astype(jnp.float32) * cfg.embedding_multiplier).astype(out.dtype)
     return out.astype(jnp.dtype(cfg.activation_dtype))
@@ -135,7 +135,7 @@ def lm_head_loss(x, table, labels, cfg: ModelConfig,
     t_spec = _spec(ctx, ("vocab", None), table.shape)
     l_spec = _spec(ctx, ("batch", None), labels.shape)
     m_spec = _spec(ctx, ("batch", None), mask.shape)
-    loss, z = shard_map(
+    loss, z = jax.shard_map(
         body, mesh=ctx.mesh, in_specs=(x_spec, t_spec, l_spec, m_spec),
         out_specs=(P(), P()), check_vma=False)(x, table, labels, mask)
     return loss, z
@@ -182,5 +182,5 @@ def sharded_greedy(x, table, cfg: ModelConfig) -> jax.Array:
     x_spec = _spec(ctx, ("batch", None), x.shape)
     t_spec = _spec(ctx, ("vocab", None), table.shape)
     out_spec = _spec(ctx, ("batch",), (x.shape[0],))
-    return shard_map(body, mesh=ctx.mesh, in_specs=(x_spec, t_spec),
-                         out_specs=out_spec, check_vma=False)(x, table)
+    return jax.shard_map(body, mesh=ctx.mesh, in_specs=(x_spec, t_spec),
+                             out_specs=out_spec, check_vma=False)(x, table)

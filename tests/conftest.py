@@ -1,133 +1,16 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here — tests see 1 CPU device; the
 multi-device paths are exercised in subprocesses (test_multidevice.py) and
 by the dry-run (launch/dryrun.py sets the flag itself).
-
-Also installs a minimal ``hypothesis`` fallback when the real package is
-not available (see requirements-dev.txt), so the property-based tests in
-test_core.py / test_quantization.py degrade to a deterministic sampled
-sweep instead of erroring at collection.
 """
 import os
 import sys
-import types
 
 import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-
-def _install_hypothesis_fallback():
-    """Register stub ``hypothesis`` / ``hypothesis.strategies`` modules.
-
-    The stub draws a deterministic handful of samples per strategy instead
-    of doing real property-based search — enough to keep the invariants
-    exercised where the dev dependency is missing.
-    """
-    try:
-        import hypothesis  # noqa: F401
-        return
-    except ImportError:
-        pass
-
-    FALLBACK_EXAMPLES = 5
-
-    class _Unsatisfied(Exception):
-        """Raised by the shim's assume(); the @given wrapper skips the
-        example, mirroring real hypothesis filtering."""
-
-    def assume(condition):
-        if not condition:
-            raise _Unsatisfied()
-        return True
-
-    def note(msg):
-        # real hypothesis attaches notes to the failing example report;
-        # the deterministic shim just prints (visible with pytest -s / on
-        # failure via captured stdout)
-        print(f"note: {msg}")
-
-    class _Strategy:
-        def __init__(self, draw):
-            self.draw = draw
-
-    def integers(lo, hi):
-        return _Strategy(lambda rng: int(rng.integers(lo, hi + 1)))
-
-    def floats(lo, hi, **_):
-        return _Strategy(lambda rng: float(rng.uniform(lo, hi)))
-
-    def booleans():
-        return _Strategy(lambda rng: bool(rng.integers(0, 2)))
-
-    def sampled_from(seq):
-        seq = list(seq)
-        return _Strategy(lambda rng: seq[int(rng.integers(len(seq)))])
-
-    def given(*_args, **strategies):
-        if _args:
-            raise TypeError("fallback @given supports keyword strategies "
-                            "only")
-
-        def deco(fn):
-            def wrapper():
-                rng = np.random.default_rng(0)
-                # @settings may sit above @given (tags the wrapper) or
-                # below it (tags fn) — honor both, like real hypothesis
-                n = getattr(wrapper, "_max_examples",
-                            getattr(fn, "_max_examples", FALLBACK_EXAMPLES))
-                for _ in range(n):
-                    try:
-                        fn(**{k: s.draw(rng) for k, s in strategies.items()})
-                    except _Unsatisfied:
-                        continue            # assume() filtered the example
-
-            # plain attribute copy (not functools.wraps): pytest must see a
-            # zero-arg signature, or it would try to inject the strategy
-            # parameters as fixtures
-            wrapper.__name__ = fn.__name__
-            wrapper.__doc__ = fn.__doc__
-            wrapper.__module__ = fn.__module__
-            wrapper.hypothesis_fallback = True
-            return wrapper
-
-        return deco
-
-    def settings(max_examples=FALLBACK_EXAMPLES, **_):
-        def deco(fn):
-            fn._max_examples = min(max_examples, FALLBACK_EXAMPLES)
-            return fn
-
-        return deco
-
-    hyp = types.ModuleType("hypothesis")
-    st = types.ModuleType("hypothesis.strategies")
-    st.integers, st.floats = integers, floats
-    st.booleans, st.sampled_from = booleans, sampled_from
-    hyp.given, hyp.settings, hyp.strategies = given, settings, st
-    hyp.assume, hyp.note = assume, note
-    hyp.__is_fallback__ = True
-    sys.modules["hypothesis"] = hyp
-    sys.modules["hypothesis.strategies"] = st
-
-
-_install_hypothesis_fallback()
-
 import jax      # noqa: E402
 import pytest   # noqa: E402
-
-
-def pytest_addoption(parser):
-    # CI runs ``pytest tests/test_scheduler_properties.py
-    # --hypothesis-seed=0``. With the real package that option comes from
-    # the hypothesis pytest plugin; the fallback shim (deterministic,
-    # seed-0 by construction) must accept it too or the CI line dies on
-    # an unknown argument.
-    import sys
-    if getattr(sys.modules.get("hypothesis"), "__is_fallback__", False):
-        parser.addoption(
-            "--hypothesis-seed", action="store", default=None,
-            help="accepted for CI parity; the hypothesis fallback shim "
-                 "is already deterministic (numpy seed 0)")
 
 jax.config.update("jax_enable_x64", False)
 

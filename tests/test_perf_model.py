@@ -9,8 +9,8 @@ import statistics
 
 import pytest
 
-from repro.core.backend import (BACKENDS, DEFAULT_BACKEND, TPU_V5E,
-                                BackendSpec, D2H_H2D_RATIO)
+from repro.core.backend import (BACKENDS, TPU_V5E, BackendSpec,
+                                D2H_H2D_RATIO, spec_for)
 from repro.core.transfer import TransferStats
 from repro.serving.perf_model import (DEFAULT_FIX_TOKENS, DEFAULT_OVERHEAD,
                                       KNEE_FRAC, _SCALE_REF_TOKENS,
@@ -254,10 +254,11 @@ def test_default_overhead_constants_match_the_paper():
 def test_backend_spec_replaces_roofline_literals():
     from repro.launch.hlo_analysis import (HBM_BW, ICI_BW, PEAK_FLOPS_BF16,
                                            roofline_terms)
-    assert PEAK_FLOPS_BF16 == DEFAULT_BACKEND.peak_flops_bf16
-    assert HBM_BW == DEFAULT_BACKEND.hbm_bw
-    assert ICI_BW == DEFAULT_BACKEND.ici_bw
-    assert BACKENDS[TPU_V5E.name] is TPU_V5E
+    assert PEAK_FLOPS_BF16 == TPU_V5E.peak_flops_bf16
+    assert HBM_BW == TPU_V5E.hbm_bw
+    assert ICI_BW == TPU_V5E.ici_bw
+    # keyed by the device_kind the chip reports
+    assert BACKENDS["TPU v5 lite"] is TPU_V5E
 
     class S:
         dot_flops = 197e12
@@ -274,6 +275,33 @@ def test_backend_spec_replaces_roofline_literals():
                        h2d_bw=TPU_V5E.h2d_bw, d2h_bw=TPU_V5E.d2h_bw)
     assert roofline_terms(S(), spec=half)["compute_s"] == pytest.approx(2.0)
     assert half.peak_flops("w8a8") == TPU_V5E.peak_flops_int8 / 2
+
+
+class _Dev:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+@pytest.mark.parametrize("platform,kind,want", [
+    ("tpu", "TPU v5 lite", TPU_V5E),
+    ("cpu", "cpu", TPU_V5E),            # CPU tests price the v5e
+    ("tpu", "TPU v4", None),            # unknown accelerator: an error
+    ("gpu", "NVIDIA H100", None),
+])
+def test_spec_for_keys_on_device_kind(platform, kind, want):
+    if want is None:
+        with pytest.raises(ValueError, match=kind):
+            spec_for(_Dev(platform, kind))
+    else:
+        assert spec_for(_Dev(platform, kind)) is want
+
+
+def test_perf_model_prices_the_engine_device():
+    import jax
+    assert PerfModel.for_params({"w": 1.0}).spec is spec_for(
+        jax.devices()[0])
+    with pytest.raises(ValueError):
+        PerfModel.for_params({"w": 1.0}, device=_Dev("tpu", "TPU v4"))
 
 
 # ---- ServiceEstimator: cold-start precedence (the PR 9 bugfixes) ----------
