@@ -1356,7 +1356,7 @@ _PM_PASSES = 5             # drains per cell, calibration AND measurement
 
 def _pm_cell_pass_s(eng, cfg, stage, length, seed, new_tokens=1):
     """Serving-level seconds per ``stage`` dispatch of ONE single-request
-    drain. With JAX async dispatch the executor's per-stage timer sees
+    drain. With JAX async dispatch a timer around the stage call sees
     only dispatch latency, not device time (executor.py), so the cell is
     timed by wall clock around the whole drain — the engine syncs on
     every emitted token, so the wall time IS the step cost, admission

@@ -5,8 +5,9 @@
   first, size x time batch formation), per-request deadline tracking, and
   completion accounting into Telemetry.
 - ``executor.py``   — StageExecutor: compiled-stage cache keyed by
-  (stage, shape-bucket) with compile-count and per-stage dispatch
-  telemetry; absorbs the engines' private jit caches (T5 bucketing).
+  (stage, shape-bucket) with compile and per-stage call counts and a
+  ``serve.dispatch`` profiler span; absorbs the engines' private jit
+  caches (T5 bucketing).
 - ``telemetry.py``  — shared stats surface: QPS, p50/p95/p99 latency,
   queue depth, SLA-miss fraction, compile counts, per-stage dispatches;
   consumed by launch/serve.py, the examples, and benchmarks.

@@ -192,7 +192,7 @@ class DLRMEngine:
         self.telemetry.record_serving_window(time.perf_counter() - t0)
         if measure:
             # stage re-execution for timing must not double-count the
-            # T6 transfer stats or dispatch telemetry collected by the
+            # T6 transfer stats or stage call counts collected by the
             # production pass above
             with self._suppress_traffic_stats():
                 stats.stage_time_s = self._pipeline.measure_stages(
@@ -202,16 +202,14 @@ class DLRMEngine:
     @contextmanager
     def _suppress_traffic_stats(self):
         """Exclude non-production traffic (warm-up, measurement re-runs)
-        from transfer stats and per-stage dispatch telemetry."""
+        from transfer stats and per-stage call counts."""
         self._collect_transfer_stats = False
         calls = dict(self.telemetry.stage_calls)
-        disp = dict(self.telemetry.stage_dispatch_s)
         try:
             yield
         finally:
             self._collect_transfer_stats = True
             self.telemetry.stage_calls = calls
-            self.telemetry.stage_dispatch_s = disp
 
 
 def make_replicas(cfg: DLRMConfig, assignment: TableAssignment, params: Any,

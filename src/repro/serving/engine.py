@@ -45,6 +45,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core.bucketing import pick_bucket
@@ -283,7 +284,7 @@ class InferenceEngine:
     def _build_prefill(self, bucket: int):
         cfg, max_len = self.cfg, self.max_len
 
-        def fn(params, tokens, length):
+        def prefill_step(params, tokens, length):
             valid = jnp.arange(bucket)[None, :] < length[:, None]
             caches = model_mod.init_caches(cfg, tokens.shape[0], max_len)
             x, caches, _ = model_mod.forward(
@@ -293,12 +294,12 @@ class InferenceEngine:
             nxt = model_mod.greedy_next(params, cfg, last)
             return nxt, caches
 
-        return jax.jit(fn)
+        return jax.jit(prefill_step)
 
     def _build_decode(self):
         cfg = self.cfg
 
-        def fn(params, caches, tokens, pos_vec, active):
+        def decode_step(params, caches, tokens, pos_vec, active):
             hidden, caches = model_mod.decode_step(params, cfg, tokens,
                                                    caches, pos_vec,
                                                    active=active)
@@ -306,7 +307,7 @@ class InferenceEngine:
             return nxt, caches
 
         # in-place cache update (the engine drops its old reference)
-        return jax.jit(fn, donate_argnums=(1,))
+        return jax.jit(decode_step, donate_argnums=(1,))
 
     def _build_chunk(self, bucket: int):
         """Chunk-prefill executable: ``bucket``-token chunks for the P
@@ -328,8 +329,8 @@ class InferenceEngine:
         clobber the real row."""
         cfg = self.cfg
 
-        def fn(params, caches, slots, tokens, start, write_pos, lengths,
-               last_idx):
+        def chunk_prefill_step(params, caches, slots, tokens, start,
+                               write_pos, lengths, last_idx):
             x, caches = model_mod.chunk_prefill_step(
                 params, cfg, tokens, caches, slots, start, write_pos,
                 lengths)
@@ -337,12 +338,12 @@ class InferenceEngine:
             nxt = model_mod.greedy_next(params, cfg, hidden)
             return nxt, caches
 
-        return jax.jit(fn, donate_argnums=(1,))
+        return jax.jit(chunk_prefill_step, donate_argnums=(1,))
 
     def _build_slot_write(self):
         axes = self._batch_axes
 
-        def write(dst_tree, src_tree, slots):
+        def slot_write(dst_tree, src_tree, slots):
             # src may carry trailing padded rows (fixed prefill batch);
             # only the first len(slots) rows are real
             def upd(dst, src, ax):
@@ -356,7 +357,7 @@ class InferenceEngine:
             return jax.tree.map(upd, dst_tree, src_tree, axes)
 
         # donate the destination tree: scatter in place, no full copy
-        return jax.jit(write, donate_argnums=(0,))
+        return jax.jit(slot_write, donate_argnums=(0,))
 
     # ---- movable sequence state: serialize / restore (PR 8) --------------
     def snapshot_slot(self, slot: int, length: int, *,
@@ -721,17 +722,26 @@ class InferenceEngine:
         short prompts prefills outright), then one decode step across
         the active batch — prefill work can stall decode slots for at
         most one chunk. Monolithic mode: refill every freed slot, then
-        one decode step (the pre-chunking behaviour)."""
-        if self.prefill_chunk is not None:
-            self._admit_chunk()
-        else:
-            self._admit()
-        if self.page_host:
-            # fault paged sessions back into whatever admission left free
-            # (admission first: fresh arrivals take precedence for slots,
-            # or page-in/page-out would thrash against each other)
-            self._page_in()
-        self._step()
+        one decode step (the pre-chunking behaviour).
+
+        Profiler spans, with constant names: ``serve.step`` around the
+        tick; inside it ``serve.admit`` (scheduling and grouping),
+        ``serve.prefill`` / ``serve.chunk`` (one group each) and
+        ``serve.decode``; inside those ``serve.dispatch``
+        (``StageExecutor.dispatch``) and ``serve.read`` (the blocking
+        token readback)."""
+        with TraceAnnotation("serve.step"):
+            if self.prefill_chunk is not None:
+                self._admit_chunk()
+            else:
+                self._admit()
+            if self.page_host:
+                # fault paged sessions back into whatever admission left
+                # free (admission first: fresh arrivals take precedence
+                # for slots, or page-in/page-out would thrash against
+                # each other)
+                self._page_in()
+            self._step()
 
     def _admit(self):
         """Refill freed slots: admit up to len(free) tickets, group them by
@@ -742,18 +752,19 @@ class InferenceEngine:
             # churn) so the arrival can prefill
             self._page_out_one()
         while self.free and self.scheduler.depth:
-            tickets = self.scheduler.admit(
-                min(len(self.free), self.max_prefill_batch))
-            if not tickets:
-                return
-            groups: Dict[int, List[Ticket]] = {}
-            lens: Dict[int, List[int]] = {}
-            for t in tickets:
-                req: Request = t.payload
-                L = self._eff_len(req)
-                b = pick_bucket(L, self.buckets)
-                groups.setdefault(b, []).append(t)
-                lens.setdefault(b, []).append(min(L, b))
+            with TraceAnnotation("serve.admit"):
+                tickets = self.scheduler.admit(
+                    min(len(self.free), self.max_prefill_batch))
+                if not tickets:
+                    return
+                groups: Dict[int, List[Ticket]] = {}
+                lens: Dict[int, List[int]] = {}
+                for t in tickets:
+                    req: Request = t.payload
+                    L = self._eff_len(req)
+                    b = pick_bucket(L, self.buckets)
+                    groups.setdefault(b, []).append(t)
+                    lens.setdefault(b, []).append(min(L, b))
             for b, group in groups.items():
                 self._prefill_group(b, group, lens[b])
 
@@ -765,30 +776,32 @@ class InferenceEngine:
         # lone freed slot refills with a batch-1 call, not a batch-P one.
         # Padded rows carry zero tokens / length 1 and are discarded below.
         g = len(group)
-        P = 1 << (g - 1).bit_length()
-        toks = np.zeros((P, bucket), np.int32)
-        lens = np.ones(P, np.int32)
-        for j, (t, L) in enumerate(zip(group, lengths)):
-            toks[j, :L] = t.payload.tokens[:L]
-            lens[j] = L
-        nxt, caches = self.executor.dispatch(
-            "prefill", (bucket, P, self.precision),
-            lambda: self._build_prefill(bucket),
-            self.run_params, jnp.asarray(toks), jnp.asarray(lens))
-        slots = [self.states.acquire(t) for t in group]
-        self.caches = self.executor.dispatch(
-            "slot_write", g, self._build_slot_write,
-            self.caches, caches, jnp.asarray(slots, jnp.int32))
-        nxt = np.asarray(nxt)
-        now = time.perf_counter()
-        for j, (t, slot, L) in enumerate(zip(group, slots, lengths)):
-            t.payload.output.append(int(nxt[j]))
-            t.payload.prefill_pos = L
-            self.telemetry.record_ttft((now - t.enqueue_t) * 1e3)
-            self.states.activate(t, slot, L)
-            self._last_decode[slot] = self.telemetry.steps
-        self.telemetry.prefills += g
-        self.telemetry.prefill_batches += 1
+        with TraceAnnotation("serve.prefill", bucket=bucket, rows=g):
+            P = 1 << (g - 1).bit_length()
+            toks = np.zeros((P, bucket), np.int32)
+            lens = np.ones(P, np.int32)
+            for j, (t, L) in enumerate(zip(group, lengths)):
+                toks[j, :L] = t.payload.tokens[:L]
+                lens[j] = L
+            nxt, caches = self.executor.dispatch(
+                "prefill", (bucket, P, self.precision),
+                lambda: self._build_prefill(bucket),
+                self.run_params, jnp.asarray(toks), jnp.asarray(lens))
+            slots = [self.states.acquire(t) for t in group]
+            self.caches = self.executor.dispatch(
+                "slot_write", g, self._build_slot_write,
+                self.caches, caches, jnp.asarray(slots, jnp.int32))
+            with TraceAnnotation("serve.read"):
+                nxt = np.asarray(nxt)
+            now = time.perf_counter()
+            for j, (t, slot, L) in enumerate(zip(group, slots, lengths)):
+                t.payload.output.append(int(nxt[j]))
+                t.payload.prefill_pos = L
+                self.telemetry.record_ttft((now - t.enqueue_t) * 1e3)
+                self.states.activate(t, slot, L)
+                self._last_decode[slot] = self.telemetry.steps
+            self.telemetry.prefills += g
+            self.telemetry.prefill_batches += 1
 
     # ---- chunked prefill -------------------------------------------------
     def _prefill_len(self, req: Request) -> int:
@@ -818,11 +831,14 @@ class InferenceEngine:
             self._page_out_one()        # same page-out rule as _admit
         if not self.free and not self.prefilling:
             return                      # every slot is decoding
-        group = self.scheduler.admit_coherent(
-            self.batch_slots, bucket_fn=self._chunk_bucket_of,
-            new_cap=len(self.free))
-        if group:
-            self._chunk_group(self._chunk_bucket_of(group[0]), group)
+        with TraceAnnotation("serve.admit"):
+            group = self.scheduler.admit_coherent(
+                self.batch_slots, bucket_fn=self._chunk_bucket_of,
+                new_cap=len(self.free))
+            if not group:
+                return
+            bucket = self._chunk_bucket_of(group[0])
+        self._chunk_group(bucket, group)
 
     def _chunk_group(self, bucket: int, group: List[Ticket]):
         """Run one prompt chunk for every ticket in the group in a single
@@ -832,97 +848,101 @@ class InferenceEngine:
         group ride along parked (zero tokens, dropped writes), exactly
         like idle rows ride a decode step."""
         g = len(group)
-        P = 1 << (g - 1).bit_length()       # pad like _prefill_group
-        toks = np.zeros((P, bucket), np.int32)
-        start = np.zeros(P, np.int32)
-        wpos = np.full(P, self.max_len, np.int32)   # padded: writes drop
-        lens = np.zeros(P, np.int32)                # padded: rows drop
-        last = np.zeros(P, np.int32)
-        slots: List[int] = []
-        for j, t in enumerate(group):
-            req: Request = t.payload
-            off = req.prefill_pos
-            clen = min(self._chunk_next_len(req), bucket)
-            slots.append(self.states.acquire(t))
-            snap = self._pending_restore.pop(id(t), None)
-            if snap is not None:
-                # prefix-cache hit: the cached prefix lands in the slot
-                # BEFORE this group's chunk dispatch reads the cache, so
-                # the chunk at offset ``off == snap.length`` attends a
-                # prefix identical to one it would have computed itself
-                self.restore_slot(snap, slots[-1])
-            toks[j, :clen] = req.tokens[off:off + clen]
-            start[j] = off
-            wpos[j] = off
-            lens[j] = clen
-            last[j] = clen - 1
-        slots_padded = np.asarray(slots + [slots[0]] * (P - g), np.int32)
-        nxt, self.caches = self.executor.dispatch(
-            "chunk_prefill", (bucket, P, self.precision),
-            lambda: self._build_chunk(bucket),
-            self.run_params, self.caches, jnp.asarray(slots_padded),
-            jnp.asarray(toks), jnp.asarray(start), jnp.asarray(wpos),
-            jnp.asarray(lens), jnp.asarray(last))
-        nxt = np.asarray(nxt)
-        now = time.perf_counter()
-        for j, (t, slot) in enumerate(zip(group, slots)):
-            req = t.payload
-            req.prefill_pos += int(last[j]) + 1
-            if self.prefix_cache \
-                    and req.prefill_pos % self.prefill_chunk == 0:
-                # completed chunk boundary: admit the written prefix to
-                # the cache (content-keyed, so every request sharing a
-                # system prompt dedups onto one entry)
-                self._prefix_insert(req, slot)
-            if req.prefill_pos >= self._prefill_len(req):
-                req.output.append(int(nxt[j]))
-                self.telemetry.record_ttft((now - t.enqueue_t) * 1e3)
-                self.telemetry.prefills += 1
-                self.states.activate(t, slot, req.prefill_pos)
-                self._last_decode[slot] = self.telemetry.steps
-            else:
-                self.states.park(t, slot)
-                self.scheduler.resubmit(t, size=self._chunk_next_len(req))
-        self.telemetry.prefill_batches += 1
+        with TraceAnnotation("serve.chunk", bucket=bucket, rows=g):
+            P = 1 << (g - 1).bit_length()       # pad like _prefill_group
+            toks = np.zeros((P, bucket), np.int32)
+            start = np.zeros(P, np.int32)
+            wpos = np.full(P, self.max_len, np.int32)   # padded: writes drop
+            lens = np.zeros(P, np.int32)                # padded: rows drop
+            last = np.zeros(P, np.int32)
+            slots: List[int] = []
+            for j, t in enumerate(group):
+                req: Request = t.payload
+                off = req.prefill_pos
+                clen = min(self._chunk_next_len(req), bucket)
+                slots.append(self.states.acquire(t))
+                snap = self._pending_restore.pop(id(t), None)
+                if snap is not None:
+                    # prefix-cache hit: the cached prefix lands in the slot
+                    # BEFORE this group's chunk dispatch reads the cache, so
+                    # the chunk at offset ``off == snap.length`` attends a
+                    # prefix identical to one it would have computed itself
+                    self.restore_slot(snap, slots[-1])
+                toks[j, :clen] = req.tokens[off:off + clen]
+                start[j] = off
+                wpos[j] = off
+                lens[j] = clen
+                last[j] = clen - 1
+            slots_padded = np.asarray(slots + [slots[0]] * (P - g), np.int32)
+            nxt, self.caches = self.executor.dispatch(
+                "chunk_prefill", (bucket, P, self.precision),
+                lambda: self._build_chunk(bucket),
+                self.run_params, self.caches, jnp.asarray(slots_padded),
+                jnp.asarray(toks), jnp.asarray(start), jnp.asarray(wpos),
+                jnp.asarray(lens), jnp.asarray(last))
+            with TraceAnnotation("serve.read"):
+                nxt = np.asarray(nxt)
+            now = time.perf_counter()
+            for j, (t, slot) in enumerate(zip(group, slots)):
+                req = t.payload
+                req.prefill_pos += int(last[j]) + 1
+                if self.prefix_cache \
+                        and req.prefill_pos % self.prefill_chunk == 0:
+                    # completed chunk boundary: admit the written prefix to
+                    # the cache (content-keyed, so every request sharing a
+                    # system prompt dedups onto one entry)
+                    self._prefix_insert(req, slot)
+                if req.prefill_pos >= self._prefill_len(req):
+                    req.output.append(int(nxt[j]))
+                    self.telemetry.record_ttft((now - t.enqueue_t) * 1e3)
+                    self.telemetry.prefills += 1
+                    self.states.activate(t, slot, req.prefill_pos)
+                    self._last_decode[slot] = self.telemetry.steps
+                else:
+                    self.states.park(t, slot)
+                    self.scheduler.resubmit(t, size=self._chunk_next_len(req))
+            self.telemetry.prefill_batches += 1
 
     def _step(self):
         if not self.active:
             return
-        toks = np.zeros((self.batch_slots, 1), np.int32)
-        # inactive rows (free or mid-chunked-prefill) still ride the
-        # static-shape decode dispatch: their K/V write parks at
-        # max_len-1 — a position no request ever attends (decoding stops
-        # at max_len-1) — and the model layer freezes their per-slot
-        # state under the active mask (a dummy step must not advance a
-        # mid-prefill row's ring buffer or recurrent state)
-        pos_vec = self.states.decode_positions(self.max_len - 1)
-        active_mask = self.states.active_mask()
-        for s, t in self.active.items():
-            toks[s, 0] = t.payload.output[-1]
-        nxt, self.caches = self.executor.dispatch(
-            "decode", (self.precision,), self._build_decode,
-            self.run_params, self.caches, jnp.asarray(toks),
-            jnp.asarray(pos_vec), jnp.asarray(active_mask))
-        nxt = np.asarray(nxt)
-        self.telemetry.steps += 1
-        for s in list(self.active):
-            t = self.active[s]
-            req: Request = t.payload
-            self.pos[s] += 1
-            req.output.append(int(nxt[s]))
-            self.telemetry.total_tokens += 1
-            self._last_decode[s] = self.telemetry.steps
-            if len(req.output) >= req.max_new_tokens \
-                    or self.pos[s] >= self.max_len - 1:
-                req.done = True
-                self.scheduler.complete(t)
-                # sync from the ticket, whose stamps are authoritative —
-                # rebase_pending (run_concurrent) may have shifted
-                # enqueue_t after submit stamped the request
-                req.enqueue_t = t.enqueue_t
-                req.finish_t = t.finish_t
-                self.states.release(s)
-                self._last_decode.pop(s, None)
+        with TraceAnnotation("serve.decode"):
+            toks = np.zeros((self.batch_slots, 1), np.int32)
+            # inactive rows (free or mid-chunked-prefill) still ride the
+            # static-shape decode dispatch: their K/V write parks at
+            # max_len-1 — a position no request ever attends (decoding stops
+            # at max_len-1) — and the model layer freezes their per-slot
+            # state under the active mask (a dummy step must not advance a
+            # mid-prefill row's ring buffer or recurrent state)
+            pos_vec = self.states.decode_positions(self.max_len - 1)
+            active_mask = self.states.active_mask()
+            for s, t in self.active.items():
+                toks[s, 0] = t.payload.output[-1]
+            nxt, self.caches = self.executor.dispatch(
+                "decode", (self.precision,), self._build_decode,
+                self.run_params, self.caches, jnp.asarray(toks),
+                jnp.asarray(pos_vec), jnp.asarray(active_mask))
+            with TraceAnnotation("serve.read"):
+                nxt = np.asarray(nxt)
+            self.telemetry.steps += 1
+            for s in list(self.active):
+                t = self.active[s]
+                req: Request = t.payload
+                self.pos[s] += 1
+                req.output.append(int(nxt[s]))
+                self.telemetry.total_tokens += 1
+                self._last_decode[s] = self.telemetry.steps
+                if len(req.output) >= req.max_new_tokens \
+                        or self.pos[s] >= self.max_len - 1:
+                    req.done = True
+                    self.scheduler.complete(t)
+                    # sync from the ticket, whose stamps are authoritative —
+                    # rebase_pending (run_concurrent) may have shifted
+                    # enqueue_t after submit stamped the request
+                    req.enqueue_t = t.enqueue_t
+                    req.finish_t = t.finish_t
+                    self.states.release(s)
+                    self._last_decode.pop(s, None)
 
     def run(self, requests: Sequence[Request]) -> List[Request]:
         for r in requests:

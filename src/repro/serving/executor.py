@@ -4,15 +4,16 @@ the engines.
 
 A StageExecutor is a cache of compiled callables keyed by
 ``(stage_name, shape_key)`` — e.g. ``("prefill", (bucket, batch))`` or
-``("sparse", ())`` — with compile-count and per-stage dispatch telemetry.
+``("sparse", ())`` — with compile and per-stage call counts.
 It absorbs what the seed engines hand-rolled privately:
 ``InferenceEngine._prefill_cache`` / ``_get_prefill`` and the jitted
 stages built in ``DLRMEngine.__post_init__``.
 """
 from __future__ import annotations
 
-import time
 from typing import Any, Callable, Dict, Hashable, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from repro.serving.telemetry import Telemetry
 
@@ -23,10 +24,10 @@ class StageExecutor:
     """Compiled-stage cache + dispatch wrapper.
 
     ``get`` returns (building if needed) the executable for a stage/shape;
-    ``dispatch`` additionally times the call. With JAX async dispatch the
-    recorded time is *dispatch* latency, not device time — still the right
-    thing to watch for host-side stalls (the paper's §IV-C overlap is
-    precisely about keeping dispatch off the critical path).
+    ``dispatch`` additionally counts the call and wraps it in a
+    ``serve.dispatch`` profiler span. With JAX async dispatch the call
+    returns once the work is enqueued, so the span covers the enqueue; the
+    device time is in the same trace, under the stage's program name.
     """
 
     def __init__(self, telemetry: Optional[Telemetry] = None):
@@ -45,11 +46,11 @@ class StageExecutor:
 
     def dispatch(self, stage: str, key: Hashable,
                  build_fn: Callable[[], Callable], *args, **kw) -> Any:
-        """get() + call, recording per-stage dispatch count/time."""
+        """get() + call, counting the call per stage."""
         fn = self.get(stage, key, build_fn)
-        t0 = time.perf_counter()
-        out = fn(*args, **kw)
-        self.telemetry.record_dispatch(stage, time.perf_counter() - t0)
+        with TraceAnnotation("serve.dispatch"):
+            out = fn(*args, **kw)
+        self.telemetry.record_dispatch(stage)
         return out
 
     def compiles_for(self, stage: str) -> int:

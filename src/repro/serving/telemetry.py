@@ -1,7 +1,7 @@
 """Unified serving telemetry — one stats object shared by the scheduler,
 executor, and both engines (the paper's production monitoring surface:
 QPS, tail latency, queue depth, SLA misses, compile counts, per-stage
-times). Park et al. (1811.09886) and Gupta et al. (1906.03109) both find
+call counts). Park et al. (1811.09886) and Gupta et al. (1906.03109) both find
 the batching/queueing policy — not the kernel — dominates tail latency at
 scale, so the runtime has to measure the queue, not just the device.
 """
@@ -46,7 +46,7 @@ class Telemetry:
     """Counters + distributions for one serving runtime instance.
 
     The scheduler stamps request lifecycle events (``record_latency``,
-    ``record_queue_depth``), the executor stamps compile/dispatch events
+    ``record_queue_depth``), the executor counts compiles and calls
     (``record_compile``, ``record_dispatch``), and the engines stamp
     work-item counters directly (``served``/``steps``/``prefills``/...).
     """
@@ -95,16 +95,13 @@ class Telemetry:
     # executor-side counters
     compiles: Dict[str, int] = field(default_factory=dict)
     stage_calls: Dict[str, int] = field(default_factory=dict)
-    stage_dispatch_s: Dict[str, float] = field(default_factory=dict)
 
     # ---- executor hooks --------------------------------------------------
     def record_compile(self, stage: str):
         self.compiles[stage] = self.compiles.get(stage, 0) + 1
 
-    def record_dispatch(self, stage: str, seconds: float):
+    def record_dispatch(self, stage: str):
         self.stage_calls[stage] = self.stage_calls.get(stage, 0) + 1
-        self.stage_dispatch_s[stage] = \
-            self.stage_dispatch_s.get(stage, 0.0) + seconds
 
     # ---- scheduler hooks -------------------------------------------------
     def record_queue_depth(self, depth: int):
@@ -228,7 +225,7 @@ class Telemetry:
 
     def reset_serving_stats(self):
         """Zero every traffic-scoped counter/distribution (after warm-up) —
-        including per-stage dispatch counts/times, so summary() stays
+        including per-stage call counts, so summary() stays
         internally consistent. Only ``compiles`` survives: executables are
         cumulative engine state, not traffic.
 
