@@ -455,83 +455,114 @@ def _chunk_prefill_local(p, q, k_new, v_new, cache, slots, start, write_pos,
 # decode (single new token against a cache)
 # --------------------------------------------------------------------------
 
-def decode_attention(p, x, cache, pos, cfg: ModelConfig, kind: str,
-                     active=None):
-    """x (B,1,d); pos int32 scalar OR per-sequence (B,) vector (#tokens
-    already in each slot's cache — continuous batching decodes slots at
-    different positions). ``active`` (B,) bool marks the rows really
-    decoding: inactive rows (free or mid-chunked-prefill) ride the
-    static-shape dispatch but must leave their cache row untouched — a
-    dummy write at ``pos % window`` would clobber a mid-prefill row's
-    ring, so inactive rows write back the value already at their write
-    position (an O(B) gather, not a cache copy). Returns (y (B,1,d),
-    new_cache). Dispatches to the sequence-sharded path when the mesh
-    shards the cache sequence axis."""
-    B = x.shape[0]
+def _decode_positions(pos, B: int):
+    """``pos`` scalar or per-slot (B,) -> int32 (B,)."""
     pos = jnp.asarray(pos, jnp.int32)
-    per_slot = pos.ndim == 1
-    pos_b = pos if per_slot else jnp.full((B,), pos, jnp.int32)
+    return pos if pos.ndim == 1 else jnp.full((B,), pos, jnp.int32)
+
+
+def decode_attend(p, x, cache, pos, cfg: ModelConfig, kind: str):
+    """Attention of one new token per row against the cache as it stands,
+    read-only, plus the token's own K/V as one extra key.
+
+    x (B,1,d); pos int32 scalar OR per-sequence (B,) vector (#tokens
+    already in each slot's cache — continuous batching decodes slots at
+    different positions). The cache keys visible to row b are exactly
+    those a write-then-attend step would see besides the new token:
+    global, positions ``< pos``; local ring, slots ``< pos`` until the
+    ring is full, then every slot but ``pos % window`` (it holds
+    ``pos - window``, which the new token evicts). Returns (y (B,1,d),
+    rows): ``rows`` holds the token's K/V in the cache's dtype (int8
+    values plus fp16 scales for an int8 cache), shaped like a cache with
+    one position, for ``write_decode_rows``. Dispatches to the
+    sequence-sharded path when the mesh shards the cache sequence axis."""
+    B = x.shape[0]
+    per_slot = jnp.ndim(pos) == 1
+    pos_b = _decode_positions(pos, B)
     if cfg.rope_mode == "mrope":
         positions = jnp.broadcast_to(pos_b[None, :, None], (3, B, 1))
     else:
         positions = pos_b[:, None]
     q, k_new, v_new = _project_qkv(p, x, cfg, positions)
 
-    slots = cache["k"].shape[1]
-    write_at = jnp.mod(pos_b, slots) if kind == "local" else pos_b
-    quant = "k_scale" in cache
-
-    # sequence-sharded fast path: scalar-position batches only — it has
-    # no per-slot write offsets and no active-mask freeze, so serving's
-    # continuous batching (per-slot pos, inactive rows) must take the
-    # general path below, which is correct under any mesh
-    if kind == "global" and mesh_axis_size("kv_seq") > 1 and not quant \
-            and not per_slot and active is None:
-        o, new_cache = _decode_seq_sharded(q, k_new, v_new, cache, pos, cfg)
-        return _out_proj(p, o), new_cache
-
-    def write_one(c, new, at):
-        start = (at,) + (0,) * (c.ndim - 1)
-        return jax.lax.dynamic_update_slice(c, new.astype(c.dtype), start)
-
-    def guard(val, name):
-        """Inactive rows re-write the value already at their write slot."""
-        if active is None:
-            return val
-        at = write_at.reshape((B,) + (1,) * (val.ndim - 1))
-        old = jnp.take_along_axis(cache[name], at, axis=1).astype(val.dtype)
-        act = jnp.asarray(active, bool).reshape((B,) + (1,) * (val.ndim - 1))
-        return jnp.where(act, val, old)
-
-    new_cache = {}
-    if quant:
+    if "k_scale" in cache:
         kq, ks = _kv_quant(k_new)
         vq, vs = _kv_quant(v_new)
-        for name, val in (("k", kq), ("v", vq),
-                          ("k_scale", ks), ("v_scale", vs)):
-            new_cache[name] = jax.vmap(write_one)(cache[name],
-                                                  guard(val, name), write_at)
-        ck = _kv_dequant(new_cache["k"], new_cache["k_scale"], x.dtype)
-        cv = _kv_dequant(new_cache["v"], new_cache["v_scale"], x.dtype)
+        rows = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+        # the new key as a later step will read it back
+        k_self = _kv_dequant(kq, ks, x.dtype)
+        v_self = _kv_dequant(vq, vs, x.dtype)
+        ck = _kv_dequant(cache["k"], cache["k_scale"], x.dtype)
+        cv = _kv_dequant(cache["v"], cache["v_scale"], x.dtype)
     else:
-        for name, val in (("k", k_new), ("v", v_new)):
-            new_cache[name] = jax.vmap(write_one)(cache[name],
-                                                  guard(val, name), write_at)
-        ck, cv = new_cache["k"], new_cache["v"]
-    idx = jnp.arange(slots)
+        rows = {"k": k_new.astype(cache["k"].dtype),
+                "v": v_new.astype(cache["v"].dtype)}
+        k_self, v_self = rows["k"], rows["v"]
+        ck, cv = cache["k"], cache["v"]
+
+    # sequence-sharded fast path: scalar-position batches only (it has no
+    # per-slot offsets); serving's continuous batching takes the general
+    # path below, which is correct under any mesh
+    if kind == "global" and mesh_axis_size("kv_seq") > 1 \
+            and "k_scale" not in cache and not per_slot:
+        o = _decode_seq_sharded(q, k_self, v_self, cache, pos_b[0], cfg)
+        return _out_proj(p, o), rows
+
+    slots = cache["k"].shape[1]
+    idx = jnp.arange(slots)[None, :]
+    before = idx < pos_b[:, None]
     if kind == "local":
-        # ring buffer: once full, every slot holds one of the last W tokens
-        valid = jnp.where(pos_b[:, None] >= slots,
-                          jnp.ones((B, slots), bool),
-                          idx[None, :] <= pos_b[:, None])
-    else:
-        valid = idx[None, :] <= pos_b[:, None]
-    scores = _gqa_scores(q, ck, cfg)                      # (B,K,G,1,slots)
-    scores = jnp.where(valid[:, None, None, None, :], scores,
-                       jnp.asarray(NEG_INF, scores.dtype))
-    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-    o = _gqa_out(probs, cv)
-    return _out_proj(p, o), new_cache
+        # ring buffer: once full, every slot but the one the new token
+        # overwrites holds one of the last window - 1 tokens
+        before = jnp.where(pos_b[:, None] >= slots,
+                           idx != jnp.mod(pos_b, slots)[:, None], before)
+    s_cache = _gqa_scores(q, ck, cfg)                     # (B,K,G,1,slots)
+    s_cache = jnp.where(before[:, None, None, None, :], s_cache,
+                        jnp.asarray(NEG_INF, s_cache.dtype))
+    s_cache = s_cache.astype(jnp.float32)
+    s_self = _gqa_scores(q, k_self, cfg).astype(jnp.float32)   # (...,1)
+    m = jnp.maximum(jnp.max(s_cache, axis=-1, keepdims=True), s_self)
+    p_cache = jnp.exp(s_cache - m)
+    p_self = jnp.exp(s_self - m)
+    denom = jnp.sum(p_cache, axis=-1, keepdims=True) + p_self
+    # both halves summed in float32, cast once
+    o = (jnp.einsum("bkgst,btkd->bskgd", (p_cache / denom).astype(cv.dtype),
+                    cv, preferred_element_type=jnp.float32)
+         + jnp.einsum("bkgst,btkd->bskgd", p_self / denom,
+                      v_self.astype(jnp.float32)))
+    o = o.reshape(B, 1, -1, o.shape[-1]).astype(cv.dtype)
+    return _out_proj(p, o), rows
+
+
+def write_decode_rows(cache, rows, pos, kind: str, active=None):
+    """Write each row's new K/V (``rows`` from ``decode_attend``) into
+    ``cache`` at its position — the ring offset ``pos % window`` for a
+    local layer — as one in-place scatter per leaf. Leaves may carry
+    leading layer axes (a scanned stack of caches, rows stacked the same
+    way). ``active`` (B,) bool marks the rows really decoding: the others
+    (free or mid-chunked-prefill rows riding the static-shape dispatch)
+    write out of range and drop, so their cache rows stay bit for bit."""
+    lead = cache["k"].ndim - 4
+    B, slots = cache["k"].shape[lead:lead + 2]
+    pos_b = _decode_positions(pos, B)
+    at = jnp.mod(pos_b, slots) if kind == "local" else pos_b
+    if active is not None:
+        at = jnp.where(jnp.asarray(active, bool), at, slots)
+    where = (slice(None),) * lead + (jnp.arange(B), at)
+    new_row = (slice(None),) * lead + (slice(None), 0)
+    return {name: c.at[where].set(rows[name][new_row].astype(c.dtype),
+                                  mode="drop")
+            for name, c in cache.items()}
+
+
+def decode_attention(p, x, cache, pos, cfg: ModelConfig, kind: str,
+                     active=None):
+    """One decode step of one layer: ``decode_attend`` then
+    ``write_decode_rows``. Returns (y (B,1,d), new_cache). The model's
+    decode step runs the two halves apart, writing every layer's rows
+    after the layer scan."""
+    y, rows = decode_attend(p, x, cache, pos, cfg, kind)
+    return y, write_decode_rows(cache, rows, pos, kind, active)
 
 
 def merge_partials(o_parts, m_parts, l_parts, axis=0):
@@ -544,12 +575,15 @@ def merge_partials(o_parts, m_parts, l_parts, axis=0):
     return o / l[..., None]
 
 
-def _decode_seq_sharded(q, k_new, v_new, cache, pos, cfg: ModelConfig):
+def _decode_seq_sharded(q, k_self, v_self, cache, pos, cfg: ModelConfig):
     """Decode attention with the KV cache sharded along sequence on the mesh
     (paper T1/T9 analogue: partial results merged device-to-device, host-free).
 
-    Each shard computes a local flash partial (o, m, l); partials merge with a
-    tiny psum instead of gathering the cache.
+    Each shard computes a flash partial (o, m, l) over its read-only
+    positions ``< pos``; the shards' partials merge with a tiny psum
+    instead of gathering the cache, and the new token (``k_self`` /
+    ``v_self``) joins that merge as one more partial. Returns o
+    (B,1,H,hd); the caller writes the new row.
     """
     ctx = current_ctx()
     mesh = ctx.mesh
@@ -561,29 +595,18 @@ def _decode_seq_sharded(q, k_new, v_new, cache, pos, cfg: ModelConfig):
     cache_spec = logical_to_spec(Logical("batch", "kv_seq", "kv_heads", None),
                                  ctx.rules, mesh, cache["k"].shape)
     qkv_spec = logical_to_spec(Logical("batch", None, "kv_heads", None),
-                               ctx.rules, mesh, k_new.shape)
+                               ctx.rules, mesh, k_self.shape)
     q_spec = logical_to_spec(Logical("batch", None, "heads", None),
                              ctx.rules, mesh, q.shape)
 
-    def body(q, k_new, v_new, ck, cv, pos):
-        rank = jax.lax.axis_index(seq_axes)
-        start = rank * S_local
-        local_pos = jnp.clip(pos - start, 0, S_local)
-        owner = (pos >= start) & (pos < start + S_local)
-        kw = jnp.where(owner, pos - start, 0)
-        upd_k = jnp.where(owner, k_new.astype(ck.dtype),
-                          jax.lax.dynamic_slice(ck, (0, kw, 0, 0), k_new.shape))
-        upd_v = jnp.where(owner, v_new.astype(cv.dtype),
-                          jax.lax.dynamic_slice(cv, (0, kw, 0, 0), v_new.shape))
-        ck = jax.lax.dynamic_update_slice(ck, upd_k, (0, kw, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cv, upd_v, (0, kw, 0, 0))
-        valid = jnp.arange(S_local) < jnp.where(owner, local_pos + 1, local_pos)
+    def body(q, k_self, v_self, ck, cv, pos):
+        start = jax.lax.axis_index(seq_axes) * S_local
+        valid = jnp.arange(S_local) < jnp.clip(pos - start, 0, S_local)
         scores = _gqa_scores(q, ck, cfg)                  # (B,K,G,1,S_local)
         scores = jnp.where(valid[None, None, None, None, :], scores, NEG_INF)
         m = jnp.max(scores, axis=-1)                       # (B,K,G,1)
         # guard fully-masked shards
-        has_any = jnp.any(valid)
-        m_safe = jnp.where(has_any, m, NEG_INF)
+        m_safe = jnp.where(jnp.any(valid), m, NEG_INF)
         p_ = jnp.exp(scores - m_safe[..., None])
         p_ = jnp.where(valid[None, None, None, None, :], p_, 0.0)
         l = jnp.sum(p_, axis=-1)
@@ -595,14 +618,20 @@ def _decode_seq_sharded(q, k_new, v_new, cache, pos, cfg: ModelConfig):
         o = jax.lax.psum(o.astype(jnp.float32) * w[..., None], seq_axes)
         lsum = jax.lax.psum(l * w, seq_axes)
         o = o / jnp.maximum(lsum[..., None], 1e-30)
+        # the new token: a one-key partial (its own value, weight 1)
+        s_self = _gqa_scores(q, k_self, cfg)[..., 0].astype(jnp.float32)
+        v_b = jnp.swapaxes(v_self, 1, 2)[:, :, None]       # (B,K,1,1,hd)
+        o = merge_partials(
+            jnp.stack([o, jnp.broadcast_to(v_b, o.shape).astype(o.dtype)]),
+            jnp.stack([M.astype(jnp.float32), s_self]),
+            jnp.stack([lsum, jnp.ones_like(lsum)]))
         B, K, G, S1, hd = o.shape
         o = jnp.swapaxes(o, 1, 3).reshape(B, S1, K * G, hd)
-        return o.astype(q.dtype), ck, cv
+        return o.astype(q.dtype)
 
-    o, ck, cv = jax.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(q_spec, qkv_spec, qkv_spec, cache_spec, cache_spec, P()),
-        out_specs=(q_spec, cache_spec, cache_spec),
+        out_specs=q_spec,
         check_vma=False,
-    )(q, k_new, v_new, cache["k"], cache["v"], pos)
-    return o, {"k": ck, "v": cv}
+    )(q, k_self, v_self, cache["k"], cache["v"], pos)

@@ -66,6 +66,17 @@ def init_block_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int,
     raise ValueError(kind)
 
 
+def write_decode(cache, new, kind: str, pos, active=None):
+    """Second half of a decode step for one block (or a scanned stack of
+    blocks): ``new`` is what ``apply_block`` returned in decode mode.
+    Attention blocks write their new K/V rows into ``cache`` in place;
+    SSM / RG-LRU states are small, per-slot and replaced whole."""
+    if kind in (ATTN_GLOBAL, ATTN_LOCAL, "decoder"):
+        akind = ATTN_GLOBAL if kind == "decoder" else kind
+        return attn.write_decode_rows(cache, new, pos, akind, active)
+    return new
+
+
 def _residual_mlp(p, x, cfg: ModelConfig, aux):
     h = apply_norm(p["pre_mlp_norm"], x, cfg.norm_type, cfg.norm_eps)
     if "moe" in p:
@@ -87,9 +98,11 @@ def apply_block(p, x, cfg: ModelConfig, kind: str, *, mode: str,
     ``(slots, start, write_pos, lengths)``), or 'decode' (``active``
     (B,) bool marks the rows really decoding; inactive rows' per-slot
     state is frozen so a dummy step cannot corrupt a mid-chunked-prefill
-    row). Every state-carrying kind chunks: global KV scatters at
-    offsets, local rings write at ring offsets, SSM / RG-LRU carry the
-    entering state + conv tail across the boundary."""
+    row; attention blocks return their new K/V rows in place of a
+    cache, for ``write_decode``). Every state-carrying kind chunks:
+    global KV scatters at offsets, local rings write at ring offsets,
+    SSM / RG-LRU carry the entering state + conv tail across the
+    boundary."""
     h = apply_norm(p["pre_norm"], x, cfg.norm_type, cfg.norm_eps)
     new_cache = cache
 
@@ -102,8 +115,10 @@ def apply_block(p, x, cfg: ModelConfig, kind: str, *, mode: str,
     if kind in (ATTN_GLOBAL, ATTN_LOCAL, "decoder"):
         akind = ATTN_GLOBAL if kind == "decoder" else kind
         if mode == "decode":
-            y, new_cache = attn.decode_attention(p["attn"], h, cache, pos,
-                                                 cfg, akind, active=active)
+            # the new token's K/V rows, not a whole cache: write_decode
+            # writes them once the layer loop is done
+            y, new_cache = attn.decode_attend(p["attn"], h, cache, pos, cfg,
+                                              akind)
         elif mode == "chunk":
             y, new_cache = attn.chunk_prefill_attention(p["attn"], h, cache,
                                                         pos, cfg, akind)

@@ -99,7 +99,10 @@ def _run_stack(params_scan, params_tail, x, cfg: ModelConfig, kinds_unit,
                kinds_tail, *, mode, positions=None, caches=None, pos=None,
                kv_valid=None, cross_kv=None, cross_valid=None,
                causal=True, remat=False, active=None):
-    """Scan the superblock unit, then the unrolled tail."""
+    """Scan the superblock unit, then the unrolled tail. In decode mode
+    the scan carries attention caches read-only and stacks only the new
+    K/V rows; they are written into the caches after the loop, one
+    scatter per leaf for all scanned layers."""
     n_pos = len(kinds_unit)
     aux0 = jnp.zeros((), jnp.float32)
 
@@ -144,6 +147,13 @@ def _run_stack(params_scan, params_tail, x, cfg: ModelConfig, kinds_unit,
         tail_caches.append(nc)
 
     new_caches = None
+    if mode == "decode":
+        scan_caches = tuple(
+            blk.write_decode(c, new, kind, pos, active)
+            for c, new, kind in zip(caches["scan"], scan_caches, kinds_unit))
+        tail_caches = [
+            blk.write_decode(c, new, kind, pos, active)
+            for c, new, kind in zip(caches["tail"], tail_caches, kinds_tail)]
     if mode != "full":
         new_caches = {"scan": scan_caches, "tail": tuple(tail_caches)}
     return x, new_caches, aux

@@ -13,6 +13,7 @@ test set from its siblings.
 import dataclasses
 import functools
 import os
+import re
 import types
 
 import jax
@@ -75,31 +76,69 @@ def test_flash_attn_compiles_for_v5e(one_chip, kv_heads):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("stage", ["decode", "chunk"])
-def test_engine_step_compiles_for_v5e(one_chip, stage):
-    """The engine's decode step and its 128-token chunk-prefill step for
-    8 slots x 1024 positions, deepseek-7b widths at 2 layers."""
-    cfg = dataclasses.replace(get_config("deepseek-7b"), num_layers=2)
-    slots, max_len, chunk = 8, 1024, 128
+def _compile_engine_step(sharding, cfg, stage, slots=8, max_len=1024,
+                         chunk=128):
+    """The engine's decode or chunk-prefill executable for ``slots`` x
+    ``max_len`` positions, compiled for the described chip; returns
+    (compiled, abstract caches)."""
     place = lambda tree: jax.tree.map(
-        lambda a: _spec(one_chip, a.shape, a.dtype), tree)
+        lambda a: _spec(sharding, a.shape, a.dtype), tree)
     params = place(jax.eval_shape(functools.partial(model_mod.init_params,
                                                     cfg),
                                   jax.random.PRNGKey(0)))
     caches = place(jax.eval_shape(
         lambda: model_mod.init_caches(cfg, slots, max_len)))
-    rows = lambda *shape: _spec(one_chip, (slots,) + shape, jnp.int32)
+    rows = lambda *shape: _spec(sharding, (slots,) + shape, jnp.int32)
     # _build_decode and _build_chunk read only the config off the engine
     eng = types.SimpleNamespace(cfg=cfg)
     if stage == "decode":
         step = InferenceEngine._build_decode(eng)
-        args = (rows(1), rows(), _spec(one_chip, (slots,), jnp.bool_))
+        args = (rows(1), rows(), _spec(sharding, (slots,), jnp.bool_))
     else:
         step = InferenceEngine._build_chunk(eng, chunk)
         # slots, tokens, start, write_pos, lengths, last_idx
         args = (rows(), rows(chunk), rows(), rows(), rows(), rows())
-    compiled = step.lower(params, caches, *args).compile()
+    return step.lower(params, caches, *args).compile(), caches
+
+
+@pytest.mark.parametrize("stage", ["decode", "chunk"])
+def test_engine_step_compiles_for_v5e(one_chip, stage):
+    """The engine's decode step and its 128-token chunk-prefill step for
+    8 slots x 1024 positions, deepseek-7b widths at 2 layers."""
+    cfg = dataclasses.replace(get_config("deepseek-7b"), num_layers=2)
+    compiled, _ = _compile_engine_step(one_chip, cfg, stage)
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < used < V5E_HBM
+
+
+def _entry_computation(hlo_text):
+    start = hlo_text.index("\nENTRY")
+    return hlo_text[start:hlo_text.index("\n}\n", start)]
+
+
+# deepseek-7b: global MHA, two scanned layers. gemma2-27b: (local, global)
+# superblocks, four layers so the unit is scanned twice and the ring
+# layers go through the loop too (one repeat compiles with no loop).
+@pytest.mark.parametrize("arch,layers", [("deepseek-7b", 2),
+                                         ("gemma2-27b", 4)])
+def test_decode_step_writes_only_new_rows(one_chip, arch, layers):
+    """The decode step carries the K/V caches through the layer loop
+    read-only and writes each layer's new row after it, in place: its
+    temporaries stay below one layer's K cache (a loop that carried
+    whole caches as outputs held a second copy of them, 403 MB here for
+    deepseek-7b), and the entry computation copies no cache-shaped
+    buffer."""
+    cfg = dataclasses.replace(get_config(arch), num_layers=layers)
+    compiled, caches = _compile_engine_step(one_chip, cfg, "decode")
+    layer_k = caches["scan"][0]["k"]
+    one_layer = layer_k.size // layer_k.shape[0] * layer_k.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < one_layer
+    dims = set()
+    for leaf in jax.tree.leaves(caches):
+        dims.update({",".join(map(str, leaf.shape)),
+                     ",".join(map(str, leaf.shape[1:]))})
+    copies = re.findall(r"= \w+\[([\d,]*)\]\S* copy\(",
+                        _entry_computation(compiled.as_text()))
+    assert not dims & set(copies), copies
