@@ -6,16 +6,23 @@ but feature ingestion still crosses host->device. The paper's two tricks
 apply directly:
 
 - *Partial tensor transfers*: sparse-index tensors are compiled at a static
-  maximum size, but only the used prefix is actually transferred; the device
-  buffer is donated and only rows [0, used) are written.
+  maximum size, but only each table's used prefix (its longest bag) is
+  transferred, padded to a width bucket.
 - *Command batching*: many small per-table index vectors are coalesced into
-  one pinned staging buffer and shipped as a single transfer.
+  one staging buffer and shipped as a single transfer.
+
+The device-side unpack is one compiled program per static
+``(B, T, Lmax)`` and staged-width bucket (the next power of two of the
+staged columns, at most ``T * Lmax``). The per-table used widths are data:
+the program derives them from the lengths it is given, so requests with
+new bag lengths reuse what is compiled. ``warm_unpack`` compiles every
+bucket of a shape up front.
 """
 from __future__ import annotations
 
-import dataclasses
-from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+import functools
+from dataclasses import dataclass
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +32,9 @@ import numpy as np
 @dataclass
 class TransferStats:
     bytes_full: int = 0          # what a naive full-size transfer would ship
-    bytes_partial: int = 0       # what we actually shipped
+    bytes_partial: int = 0       # the used prefixes shipped (and lengths)
+    bytes_padding: int = 0       # staged columns past the used prefixes,
+                                 # shipped to fill the width bucket
     num_transfers_naive: int = 0
     num_transfers_batched: int = 0
 
@@ -63,38 +72,72 @@ def pack_sparse_inputs(bags: Sequence[Sequence[Sequence[int]]],
     return SparseBatch(idx, lens)
 
 
+def staged_width(columns: int, cap: int) -> int:
+    """The width bucket that ``columns`` staged columns ship in: the next
+    power of two, at most ``cap``."""
+    return min(1 << max(columns - 1, 0).bit_length(), cap)
+
+
+def width_buckets(cap: int) -> Tuple[int, ...]:
+    """Every staged width ``staged_width`` gives for a cap."""
+    return tuple(sorted({staged_width(c, cap) for c in
+                         [1 << k for k in range(cap.bit_length())] + [cap]}))
+
+
+@functools.partial(jax.jit, static_argnames=("max_lookups",))
+def unpack_indices(staged, lengths, max_lookups: int):
+    """staged (B, W): each table's used columns, in table order, then
+    padding; lengths (B, T) -> indices (B, T, max_lookups), zero past each
+    table's used width (the largest length of its bags)."""
+    used = lengths.max(axis=0)                              # (T,)
+    start = jnp.cumsum(used) - used
+    pos = jnp.arange(max_lookups)
+    src = jnp.minimum(start[:, None] + pos[None, :], staged.shape[1] - 1)
+    vals = jnp.take(staged, src.reshape(-1), axis=1).reshape(
+        staged.shape[0], -1, max_lookups)
+    return jnp.where(pos[None, None, :] < used[None, :, None], vals, 0)
+
+
+def warm_unpack(batch_size: int, num_tables: int, max_lookups: int) -> None:
+    """Compile ``unpack_indices`` for every width bucket of one shape (on
+    the default device, where ``command_batched_transfer`` puts it)."""
+    lens = jax.device_put(np.zeros((batch_size, num_tables), np.int32))
+    for w in width_buckets(num_tables * max_lookups):
+        staged = jax.device_put(np.zeros((batch_size, w), np.int32))
+        unpack_indices(staged, lens, max_lookups).block_until_ready()
+
+
 def command_batched_transfer(batch: SparseBatch,
                              stats: Optional[TransferStats] = None,
                              device=None) -> Tuple[jax.Array, jax.Array]:
     """Coalesce all tables' used index prefixes into ONE staging buffer and
-    issue a single host->device put (command batching), then scatter back to
-    the static layout on device (cheap, device-side).
+    make a single host->device put (command batching), then unpack into
+    the static layout on device with ``unpack_indices`` (cheap,
+    device-side, compiled once per width bucket).
 
     Returns (indices (B,T,Lmax) on device, lengths (B,T) on device).
     """
     B, T, Lmax = batch.indices.shape
     used = batch.used_per_table                     # (T,)
-    # partial transfer: ship only rows [0, used_t) of each table's slice
-    staged = np.concatenate(
-        [batch.indices[:, t, :used[t]].reshape(B, -1) for t in range(T)
-         if used[t] > 0] or [np.zeros((B, 0), np.int32)], axis=1)
-    if stats is not None:
-        stats.bytes_full += batch.indices.nbytes + batch.lengths.nbytes
-        stats.bytes_partial += staged.nbytes + batch.lengths.nbytes
-        stats.num_transfers_naive += T + 1          # one per table + lengths
-        stats.num_transfers_batched += 2            # staged + lengths
-    staged_dev = jax.device_put(jnp.asarray(staged), device)
-    lens_dev = jax.device_put(jnp.asarray(batch.lengths), device)
-    # device-side unpack into the static compiled layout
-    out = jnp.zeros((B, T, Lmax), jnp.int32)
+    # partial transfer: ship only rows [0, used_t) of each table's slice,
+    # in a staging buffer padded to its width bucket
+    columns = int(used.sum())
+    staged = np.zeros((B, staged_width(columns, T * Lmax)), np.int32)
     col = 0
     for t in range(T):
         u = int(used[t])
-        if u == 0:
-            continue
-        out = out.at[:, t, :u].set(staged_dev[:, col:col + u])
+        staged[:, col:col + u] = batch.indices[:, t, :u]
         col += u
-    return out, lens_dev
+    if stats is not None:
+        live = B * columns * staged.itemsize
+        stats.bytes_full += batch.indices.nbytes + batch.lengths.nbytes
+        stats.bytes_partial += live + batch.lengths.nbytes
+        stats.bytes_padding += staged.nbytes - live
+        stats.num_transfers_naive += T + 1          # one per table + lengths
+        stats.num_transfers_batched += 2            # staged + lengths
+    staged_dev = jax.device_put(staged, device)
+    lens_dev = jax.device_put(batch.lengths, device)
+    return unpack_indices(staged_dev, lens_dev, Lmax), lens_dev
 
 
 def snapshot_device_get(tree, stats: Optional[TransferStats] = None,

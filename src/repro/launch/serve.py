@@ -482,8 +482,9 @@ def serve_dlrm(args):
         router = ReplicaRouter(dlrm_replicas(cfg, asn, params,
                                              args.replicas, **kw),
                                route=args.route, steal=args.steal)
-        # full-trace warm-up per replica (T6 unpack compiles per distinct
-        # used-prefix shape), excluded from latency/transfer stats
+        # warm-up per replica (each stage and the T6 unpack's width
+        # buckets compile on its first batch), excluded from
+        # latency/transfer stats
         for rep in router.replicas:
             rep.serve(batches, pipelined=True, warm=True)
             rep.telemetry.reset_serving_stats()
@@ -497,9 +498,8 @@ def serve_dlrm(args):
         print(router.report())
         return tel
     eng = DLRMEngine(cfg, asn, params, **kw)
-    # full-trace warm-up: the T6 unpack compiles per distinct used-prefix
-    # shape, so a partial warm would report compile stalls as serving
-    # latency; excluded from transfer + latency stats
+    # warm-up: each stage and the T6 unpack's width buckets compile on
+    # the first batch; excluded from transfer + latency stats
     eng.serve(batches, pipelined=True, warm=True)
     _, stats = eng.serve(batches, pipelined=True)
     tel = eng.telemetry

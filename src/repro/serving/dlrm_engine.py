@@ -11,6 +11,13 @@ with request N's dense overlapping request N+1's sparse (T2) and request
 N+2's host ingest — the generalization of the paper's two-stage overlap.
 Compiled stages live in the shared StageExecutor; admission/latency/SLA
 accounting flows through the shared Scheduler + Telemetry.
+
+The first request of each batch size compiles the ingest unpack for every
+staged-width bucket, so later requests compile nothing whatever their bag
+lengths. Profiler spans use the LM engine's names: ``serve.step`` around
+``step_once``, ``serve.admit`` around admission, ``serve.ingest`` around
+each request's host ingest, and the executor's ``serve.dispatch``; the
+compiled stages are the programs ``jit_sparse_fn`` and ``jit_dense_fn``.
 """
 from __future__ import annotations
 
@@ -22,12 +29,14 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.dlrm_paper import DLRMConfig
 from repro.core.partitioner import TableAssignment
 from repro.core.pipeline import Pipeline, PipelineStats
 from repro.core.transfer import (SparseBatch, TransferStats,
-                                 command_batched_transfer, naive_transfer)
+                                 command_batched_transfer, naive_transfer,
+                                 warm_unpack)
 from repro.models import dlrm as dlrm_mod
 from repro.serving.executor import StageExecutor
 from repro.serving.scheduler import Scheduler
@@ -51,6 +60,10 @@ class DLRMEngine:
 
     def __post_init__(self):
         cfg, asn = self.cfg, self.assignment
+        slab_q = (self.params or {}).get("slab_q")
+        if isinstance(slab_q, dict) and "sb" not in slab_q:
+            self.params = {**self.params,
+                           "slab_q": dlrm_mod.pack_scale_bias(slab_q)}
         self.telemetry = Telemetry()
         self.stats = self.telemetry
         self.executor = StageExecutor(self.telemetry)
@@ -59,6 +72,7 @@ class DLRMEngine:
                                    max_queue=self.max_queue,
                                    service_ms_est=self.service_ms_est)
         self._collect_transfer_stats = True
+        self._unpack_warm = set()           # batch sizes whose unpack is built
 
         def build_sparse():
             @jax.jit
@@ -91,13 +105,20 @@ class DLRMEngine:
 
     def ingest(self, batch: Dict[str, np.ndarray]) -> Dict[str, Any]:
         """Host->device input path with the paper's T6 optimizations."""
-        sb = SparseBatch(batch["indices"], batch["lengths"])
-        mover = (command_batched_transfer if self.partial_transfers
-                 else naive_transfer)
-        stats = self.transfer_stats if self._collect_transfer_stats else None
-        idx_dev, len_dev = mover(sb, stats)
-        return {"sls": (idx_dev, len_dev),
-                "dense": jnp.asarray(batch["dense"])}
+        with TraceAnnotation("serve.ingest"):
+            sb = SparseBatch(batch["indices"], batch["lengths"])
+            stats = self.transfer_stats if self._collect_transfer_stats \
+                else None
+            if self.partial_transfers:
+                B, T, L = sb.indices.shape
+                if B not in self._unpack_warm:
+                    warm_unpack(B, T, L)
+                    self._unpack_warm.add(B)
+                idx_dev, len_dev = command_batched_transfer(sb, stats)
+            else:
+                idx_dev, len_dev = naive_transfer(sb, stats)
+            return {"sls": (idx_dev, len_dev),
+                    "dense": jnp.asarray(batch["dense"])}
 
     # ---- replica protocol (ReplicaRouter) --------------------------------
     def submit(self, batch: Dict[str, np.ndarray], *,
@@ -142,14 +163,16 @@ class DLRMEngine:
         so a routed fleet actually interleaves replica steps instead of
         serially draining whole queues) and run it through the 4-stage
         pipeline, completing tickets as outputs realize."""
-        group = self.scheduler.admit(min(self.scheduler.depth,
-                                         self.step_group))
-        if not group:
-            return []
-        done = lambda i, _v: self.scheduler.complete(group[i])
-        outs, _ = self._pipeline.run([t.payload for t in group],
-                                     on_result=done)
-        return outs
+        with TraceAnnotation("serve.step"):
+            with TraceAnnotation("serve.admit"):
+                group = self.scheduler.admit(min(self.scheduler.depth,
+                                                 self.step_group))
+            if not group:
+                return []
+            done = lambda i, _v: self.scheduler.complete(group[i])
+            outs, _ = self._pipeline.run([t.payload for t in group],
+                                         on_result=done)
+            return outs
 
     def serve(self, batches: Sequence[Dict[str, np.ndarray]],
               pipelined: bool = True, warm: bool = False,
@@ -217,5 +240,8 @@ def make_replicas(cfg: DLRMConfig, assignment: TableAssignment, params: Any,
     """N DLRM engine replicas sharing one set of (quantized) tables and
     dense weights — the paper's multiple-cards-per-host deployment.
     Front with ``ReplicaRouter``."""
+    if isinstance(params.get("slab_q"), dict):
+        params = {**params,
+                  "slab_q": dlrm_mod.pack_scale_bias(params["slab_q"])}
     return [DLRMEngine(cfg, assignment, params, **engine_kw)
             for _ in range(n)]
