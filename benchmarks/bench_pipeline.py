@@ -24,8 +24,8 @@ def run() -> List[Row]:
     params = D.init_dlrm(cfg, asn, jax.random.PRNGKey(0))
     eng = DLRMEngine(cfg, asn, params)
     batches = [next(dlrm_batches(cfg, 64, seed=s)) for s in range(24)]
-    # warm every stage (the T6 unpack compiles its width buckets on the
-    # first batch), so no compile leaks into the first measured pass
+    # warm every stage (the sparse stage compiles its width buckets on
+    # the first batch), so no compile leaks into the first measured pass
     eng.serve(batches, pipelined=True, warm=True)
     _, piped = eng.serve(batches, pipelined=True, warm=True, measure=True)
     _, seq = eng.serve(batches, pipelined=False, warm=True)

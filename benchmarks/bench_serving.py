@@ -370,8 +370,8 @@ def _dlrm_summary():
     params = D.init_dlrm(cfg, asn, jax.random.PRNGKey(0))
     eng = DLRMEngine(cfg, asn, params)
     batches = [next(dlrm_batches(cfg, 32, seed=s)) for s in range(12)]
-    # warm every stage (the T6 unpack compiles its width buckets on the
-    # first batch), so no compile leaks into the measured pass
+    # warm every stage (the sparse stage compiles its width buckets on
+    # the first batch), so no compile leaks into the measured pass
     eng.serve(batches, pipelined=True, warm=True)
     eng.telemetry.reset_serving_stats()
     eng.serve(batches, pipelined=True)

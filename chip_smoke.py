@@ -307,8 +307,8 @@ def dlrm_phase(sz: Sizes) -> None:
         batches = [next(dlrm_batches(cfg, sz.dlrm_batch, seed=s))
                    for s in range(sz.dlrm_batches)]
         eng = DLRMEngine(cfg, asn, params)
-        # warm-up, as launch/serve.py does: each stage and the ingest
-        # unpack's width buckets compile on the first batch
+        # warm-up, as launch/serve.py does: each stage, and the sparse
+        # stage's width buckets, compile on the first batch
         eng.serve(batches, pipelined=True, warm=True)
         t0 = time.perf_counter()
         outs, stats = eng.serve(batches, pipelined=True)

@@ -62,8 +62,8 @@ def main(argv=None):
 
     batches = [next(dlrm_batches(cfg, args.batch_size, seed=s))
                for s in range(args.batches)]
-    # warm-up (each stage and the T6 unpack's width buckets compile on
-    # the first batch); excluded from transfer/latency stats
+    # warm-up (each stage, and the sparse stage's width buckets, compile
+    # on the first batch); excluded from transfer/latency stats
     eng.serve(batches, pipelined=True, warm=True)
 
     outs, stats = eng.serve(batches, pipelined=True)

@@ -11,12 +11,14 @@ apply directly:
 - *Command batching*: many small per-table index vectors are coalesced into
   one staging buffer and shipped as a single transfer.
 
-The device-side unpack is one compiled program per static
-``(B, T, Lmax)`` and staged-width bucket (the next power of two of the
-staged columns, at most ``T * Lmax``). The per-table used widths are data:
-the program derives them from the lengths it is given, so requests with
-new bag lengths reuse what is compiled. ``warm_unpack`` compiles every
-bucket of a shape up front.
+``staged_transfer`` ships the staging buffer, ``(B, W)`` with ``W`` a
+width bucket (the next power of two of the staged columns, at most
+``T * Lmax``); ``models/dlrm.py``'s ``sls_forward`` gathers straight from
+it. ``command_batched_transfer`` also unpacks it on the device into the
+static ``(B, T, Lmax)`` layout, one compiled program per shape and width
+bucket. The per-table used widths are data: both derive them from the
+lengths they are given, so requests with new bag lengths reuse what is
+compiled.
 """
 from __future__ import annotations
 
@@ -37,6 +39,9 @@ class TransferStats:
                                  # shipped to fill the width bucket
     num_transfers_naive: int = 0
     num_transfers_batched: int = 0
+    rows_gathered: int = 0       # index slots the sparse stage gathers:
+                                 # B x W staged, B x T x Lmax padded
+    rows_live: int = 0           # lookups inside a bag (sum of lengths)
 
     @property
     def bytes_saved_frac(self) -> float:
@@ -98,24 +103,15 @@ def unpack_indices(staged, lengths, max_lookups: int):
     return jnp.where(pos[None, None, :] < used[None, :, None], vals, 0)
 
 
-def warm_unpack(batch_size: int, num_tables: int, max_lookups: int) -> None:
-    """Compile ``unpack_indices`` for every width bucket of one shape (on
-    the default device, where ``command_batched_transfer`` puts it)."""
-    lens = jax.device_put(np.zeros((batch_size, num_tables), np.int32))
-    for w in width_buckets(num_tables * max_lookups):
-        staged = jax.device_put(np.zeros((batch_size, w), np.int32))
-        unpack_indices(staged, lens, max_lookups).block_until_ready()
-
-
-def command_batched_transfer(batch: SparseBatch,
-                             stats: Optional[TransferStats] = None,
-                             device=None) -> Tuple[jax.Array, jax.Array]:
+def staged_transfer(batch: SparseBatch,
+                    stats: Optional[TransferStats] = None,
+                    device=None) -> Tuple[jax.Array, jax.Array]:
     """Coalesce all tables' used index prefixes into ONE staging buffer and
-    make a single host->device put (command batching), then unpack into
-    the static layout on device with ``unpack_indices`` (cheap,
-    device-side, compiled once per width bucket).
+    make a single host->device put (command batching).
 
-    Returns (indices (B,T,Lmax) on device, lengths (B,T) on device).
+    Returns (staged (B, W) on device: each table's used columns in table
+    order, then zero padding to the width bucket; lengths (B, T) on
+    device).
     """
     B, T, Lmax = batch.indices.shape
     used = batch.used_per_table                     # (T,)
@@ -135,9 +131,25 @@ def command_batched_transfer(batch: SparseBatch,
         stats.bytes_padding += staged.nbytes - live
         stats.num_transfers_naive += T + 1          # one per table + lengths
         stats.num_transfers_batched += 2            # staged + lengths
-    staged_dev = jax.device_put(staged, device)
-    lens_dev = jax.device_put(batch.lengths, device)
-    return unpack_indices(staged_dev, lens_dev, Lmax), lens_dev
+        stats.rows_gathered += staged.size
+        stats.rows_live += int(batch.lengths.sum())
+    return jax.device_put(staged, device), jax.device_put(batch.lengths,
+                                                          device)
+
+
+def command_batched_transfer(batch: SparseBatch,
+                             stats: Optional[TransferStats] = None,
+                             device=None) -> Tuple[jax.Array, jax.Array]:
+    """``staged_transfer``, then unpack into the static layout on device
+    with ``unpack_indices``.
+
+    Returns (indices (B,T,Lmax) on device, lengths (B,T) on device).
+    """
+    staged_dev, lens_dev = staged_transfer(batch, stats, device)
+    if stats is not None:           # the unpacked layout is gathered whole
+        stats.rows_gathered += batch.indices.size - staged_dev.size
+    return unpack_indices(staged_dev, lens_dev,
+                          batch.indices.shape[2]), lens_dev
 
 
 def snapshot_device_get(tree, stats: Optional[TransferStats] = None,
@@ -187,5 +199,7 @@ def naive_transfer(batch: SparseBatch,
         stats.bytes_partial += batch.indices.nbytes + batch.lengths.nbytes
         stats.num_transfers_naive += batch.indices.shape[1] + 1
         stats.num_transfers_batched += batch.indices.shape[1] + 1
+        stats.rows_gathered += batch.indices.size
+        stats.rows_live += int(batch.lengths.sum())
     return (jax.device_put(jnp.asarray(batch.indices), device),
             jax.device_put(jnp.asarray(batch.lengths), device))

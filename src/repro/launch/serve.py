@@ -482,8 +482,8 @@ def serve_dlrm(args):
         router = ReplicaRouter(dlrm_replicas(cfg, asn, params,
                                              args.replicas, **kw),
                                route=args.route, steal=args.steal)
-        # warm-up per replica (each stage and the T6 unpack's width
-        # buckets compile on its first batch), excluded from
+        # warm-up per replica (each stage, and the sparse stage's width
+        # buckets, compile on its first batch), excluded from
         # latency/transfer stats
         for rep in router.replicas:
             rep.serve(batches, pipelined=True, warm=True)
@@ -498,8 +498,8 @@ def serve_dlrm(args):
         print(router.report())
         return tel
     eng = DLRMEngine(cfg, asn, params, **kw)
-    # warm-up: each stage and the T6 unpack's width buckets compile on
-    # the first batch; excluded from transfer + latency stats
+    # warm-up: each stage, and the sparse stage's width buckets, compile
+    # on the first batch; excluded from transfer + latency stats
     eng.serve(batches, pipelined=True, warm=True)
     _, stats = eng.serve(batches, pipelined=True)
     tel = eng.telemetry

@@ -24,6 +24,7 @@ import numpy as np
 from repro.configs.dlrm_paper import DLRMConfig
 from repro.core.partitioner import TableAssignment, partition_tables
 from repro.core.quantization import quantize_rows
+from repro.core.transfer import unpack_indices
 from repro.sharding.rules import (Logical, current_ctx, logical_to_spec,
                                   mesh_axis_names, mesh_axis_size)
 
@@ -140,18 +141,53 @@ def _take_dequant(slab_or_q, idx):
     return jnp.take(slab_or_q, idx, axis=0)
 
 
+def _staged_columns(lengths, width: int):
+    """The column map of a ``(B, width)`` staging buffer
+    (``core/transfer.py``: each table's used columns in table order, then
+    padding), from lengths (B, T): each column's table, (width,), and
+    whether the column holds a lookup inside its bag, (B, width)."""
+    used = lengths.max(axis=0)                                  # (T,)
+    end = jnp.cumsum(used)
+    col = jnp.arange(width)
+    table = jnp.minimum(jnp.searchsorted(end, col, side="right"),
+                        lengths.shape[1] - 1)
+    pos = col - (end - used)[table]     # padding: >= the last table's used
+    return table, pos[None, :] < lengths[:, table]
+
+
+def _sls_staged(slab, offsets, staged, lengths):
+    """staged (B, W) ids, lengths (B, T) -> pooled (B, T, D) float32:
+    gather the B x W staged rows, zero the dead columns, and sum each
+    table's columns: one (T, W) one-hot contraction at float32 precision
+    (on a TPU v5e about 3 % faster than a segment sum over the columns)."""
+    T = lengths.shape[1]
+    table, live = _staged_columns(lengths, staged.shape[1])
+    rows = _take_dequant(slab, staged + offsets[table][None, :])
+    rows = jnp.where(live[..., None], rows, 0.0).astype(jnp.float32)
+    B, W, D = rows.shape
+    onehot = (jnp.arange(T)[:, None] == table[None, :]).astype(jnp.float32)
+    pooled = jnp.dot(onehot, rows.transpose(1, 0, 2).reshape(W, B * D),
+                     precision=jax.lax.Precision.HIGHEST)
+    return pooled.reshape(T, B, D).transpose(1, 0, 2)
+
+
 def sls_forward(params, cfg: DLRMConfig, assignment: TableAssignment,
                 indices, lengths):
-    """indices (B,T,L) per-table bag indices, lengths (B,T) ->
-    pooled embeddings (B,T,D). Sharded over the slab's row axis when a mesh
-    context is active (= the paper's cards; psum gathers the sparse results
-    to the dense partition, device-to-device)."""
-    B, T, L = indices.shape
+    """indices (B,T,L) per-table bag indices, or the ingest's (B,W) staging
+    buffer (``core/transfer.py``), lengths (B,T) -> pooled embeddings
+    (B,T,D). Sharded over the slab's row axis when a mesh context is
+    active (= the paper's cards; psum gathers the sparse results to the
+    dense partition, device-to-device)."""
     offsets = jnp.asarray(assignment.table_offset, jnp.int32)
-    gidx = indices + offsets[None, :, None]
     slab = params.get("slab_q", params.get("slab"))
     ctx = current_ctx()
     rs = mesh_axis_size("table_rows")
+    if indices.ndim == 2:
+        if ctx is None or rs == 1:
+            return _sls_staged(slab, offsets, indices, lengths)
+        indices = unpack_indices(indices, lengths, cfg.max_lookups_per_table)
+    B, T, L = indices.shape
+    gidx = indices + offsets[None, :, None]
     if ctx is None or rs == 1:
         rows = _take_dequant(slab, gidx)
         return _pool_rows(rows, lengths, L).astype(jnp.float32)

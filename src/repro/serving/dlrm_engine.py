@@ -12,16 +12,20 @@ N+2's host ingest — the generalization of the paper's two-stage overlap.
 Compiled stages live in the shared StageExecutor; admission/latency/SLA
 accounting flows through the shared Scheduler + Telemetry.
 
-The first request of each batch size compiles the ingest unpack for every
-staged-width bucket, so later requests compile nothing whatever their bag
-lengths. Profiler spans use the LM engine's names: ``serve.step`` around
-``step_once``, ``serve.admit`` around admission, ``serve.ingest`` around
-each request's host ingest, and the executor's ``serve.dispatch``; the
-compiled stages are the programs ``jit_sparse_fn`` and ``jit_dense_fn``.
+The ingest ships each request's ids as one ``(B, W)`` staging buffer
+(its tables' used columns; ``W`` a width bucket) and the sparse stage
+gathers straight from it. The first request of each batch size compiles
+the sparse stage for every width bucket, so later requests compile nothing
+whatever their bag lengths. Profiler spans use the LM engine's names:
+``serve.step`` around ``step_once``, ``serve.admit`` around admission,
+``serve.ingest`` around each request's host ingest, and the executor's
+``serve.dispatch``; the compiled stages are the programs ``jit_sparse_fn``
+and ``jit_dense_fn``.
 """
 from __future__ import annotations
 
 import time
+from concurrent.futures import ThreadPoolExecutor
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -34,9 +38,8 @@ from jax.profiler import TraceAnnotation
 from repro.configs.dlrm_paper import DLRMConfig
 from repro.core.partitioner import TableAssignment
 from repro.core.pipeline import Pipeline, PipelineStats
-from repro.core.transfer import (SparseBatch, TransferStats,
-                                 command_batched_transfer, naive_transfer,
-                                 warm_unpack)
+from repro.core.transfer import (SparseBatch, TransferStats, naive_transfer,
+                                 staged_transfer, width_buckets)
 from repro.models import dlrm as dlrm_mod
 from repro.serving.executor import StageExecutor
 from repro.serving.scheduler import Scheduler
@@ -72,7 +75,7 @@ class DLRMEngine:
                                    max_queue=self.max_queue,
                                    service_ms_est=self.service_ms_est)
         self._collect_transfer_stats = True
-        self._unpack_warm = set()           # batch sizes whose unpack is built
+        self._sparse_warm = set()           # batch sizes whose widths are built
 
         def build_sparse():
             @jax.jit
@@ -80,6 +83,7 @@ class DLRMEngine:
                 return dlrm_mod.sls_forward(params, cfg, asn, indices,
                                             lengths)
             return sparse_fn
+        self._build_sparse = build_sparse
 
         def build_dense():
             @jax.jit
@@ -111,14 +115,26 @@ class DLRMEngine:
                 else None
             if self.partial_transfers:
                 B, T, L = sb.indices.shape
-                if B not in self._unpack_warm:
-                    warm_unpack(B, T, L)
-                    self._unpack_warm.add(B)
-                idx_dev, len_dev = command_batched_transfer(sb, stats)
+                if B not in self._sparse_warm:
+                    self._warm_sparse(B, T, L)
+                    self._sparse_warm.add(B)
+                idx_dev, len_dev = staged_transfer(sb, stats)
             else:
                 idx_dev, len_dev = naive_transfer(sb, stats)
             return {"sls": (idx_dev, len_dev),
                     "dense": jnp.asarray(batch["dense"])}
+
+    def _warm_sparse(self, B: int, T: int, L: int) -> None:
+        """Compile the sparse stage for every staged width of batch size
+        ``B``: lowered here, so under this thread's JAX settings, then the
+        XLA compiles in parallel threads. Later calls find them."""
+        fn = self.executor.get("sparse", (), self._build_sparse)
+        lens = jax.ShapeDtypeStruct((B, T), jnp.int32)
+        lowered = [fn.lower(self.params,
+                            jax.ShapeDtypeStruct((B, w), jnp.int32), lens)
+                   for w in width_buckets(T * L)]
+        with ThreadPoolExecutor(len(lowered)) as pool:
+            list(pool.map(lambda lo: lo.compile(), lowered))
 
     # ---- replica protocol (ReplicaRouter) --------------------------------
     def submit(self, batch: Dict[str, np.ndarray], *,

@@ -1,7 +1,7 @@
 """DLRM with the DCN V2 cross network (MLPerf's DLRM-DCNv2) served through
 ``DLRMEngine``, against the float64 reference in
 ``bench/reference/dlrm_dcn.py``; the dot interaction unchanged bit for
-bit; and the ingest unpack compiled once per width bucket."""
+bit; and the sparse stage compiled once per staged-width bucket."""
 import dataclasses
 import os
 import sys
@@ -179,57 +179,66 @@ def test_packed_scale_bias_reads_the_same_rows():
                                   np.asarray(D._take_dequant(slab, idx)))
 
 
-# ---- ingest: one compile per width bucket --------------------------------
+# ---- ingest: the staged ids, one sparse compile per width bucket ---------
 
-def _old_unpack(batch):
-    """The unpack as it was: one ``.at[:, t, :u].set`` per table."""
+def _staged(batch):
+    """Each table's used columns, in table order, then zeros to the width
+    bucket."""
     B, T, L = batch.indices.shape
     used = batch.used_per_table
-    out = np.zeros((B, T, L), np.int32)
-    for t in range(T):
-        out[:, t, :used[t]] = batch.indices[:, t, :used[t]]
-    return out
+    cols = np.concatenate([batch.indices[:, t, :used[t]] for t in range(T)],
+                          axis=1)
+    return np.pad(cols, ((0, 0), (0, TR.staged_width(cols.shape[1], T * L)
+                                  - cols.shape[1])))
 
 
-def _bags(rng, B, T, L):
+def _bags(rng, B, T, L, rows):
     """Poisson bags, each request with its own longest bag (1 to L)."""
     lens = np.minimum(rng.poisson(rng.uniform(0, L, T), (B, T)) + 1,
                       rng.integers(1, L + 1))
-    idx = rng.integers(0, 1000, (B, T, L)).astype(np.int32)
+    idx = rng.integers(0, rows, (B, T, L)).astype(np.int32)
     idx[np.arange(L)[None, None] >= lens[..., None]] = 0
     return idx, lens.astype(np.int32)
 
 
-def test_ingests_compile_nothing_after_the_first():
-    cfg = SMOKE
+def test_ingests_compile_nothing_after_the_first(model):
+    cfg, asn, params, _ = model
     B, T, L = 12, cfg.num_tables, cfg.max_lookups_per_table
-    asn = D.make_assignment(cfg, 1)
-    eng = DLRMEngine(cfg, asn, params=None)
+    eng = DLRMEngine(cfg, asn, params)
     rng = np.random.default_rng(5)
     want = TR.TransferStats()
-    widths, per_ingest = set(), []
+    widths, per_request = set(), []
     with CompileClock() as cc:
         for _ in range(20):
-            idx, lens = _bags(rng, B, T, L)
+            idx, lens = _bags(rng, B, T, L, min(cfg.table_rows))
             c0 = cc.count
             got = eng.ingest({"indices": idx, "lengths": lens,
                               "dense": np.zeros((B, 13), np.float32)})
-            per_ingest.append(cc.count - c0)
+            pooled = eng.executor.dispatch("sparse", (), eng._build_sparse,
+                                           eng.params, *got["sls"])
+            pooled.block_until_ready()
+            per_request.append(cc.count - c0)
             sb = TR.SparseBatch(idx, lens)
             np.testing.assert_array_equal(np.asarray(got["sls"][0]),
-                                          _old_unpack(sb))
+                                          _staged(sb))
             np.testing.assert_array_equal(np.asarray(got["sls"][1]), lens)
+            np.testing.assert_allclose(
+                np.asarray(pooled),
+                np.asarray(D.sls_forward(eng.params, cfg, asn, idx, lens)),
+                rtol=1e-6, atol=1e-6)
             used = int(sb.used_per_table.sum())
-            widths.add(TR.staged_width(used, T * L))
-            # the totals as the per-table unpack counted them
+            W = TR.staged_width(used, T * L)
+            widths.add(W)
+            # the byte totals as the per-table unpack counted them
             want.bytes_full += idx.nbytes + lens.nbytes
             want.bytes_partial += B * used * 4 + lens.nbytes
-            want.bytes_padding += B * (TR.staged_width(used, T * L)
-                                       - used) * 4
+            want.bytes_padding += B * (W - used) * 4
             want.num_transfers_naive += T + 1
             want.num_transfers_batched += 2
+            want.rows_gathered += B * W
+            want.rows_live += int(lens.sum())
     assert len(widths) > 2                  # the bags did change bucket
-    assert per_ingest[0] > 0 and per_ingest[1:] == [0] * 19
+    assert per_request[0] > 0 and per_request[1:] == [0] * 19
     assert eng.transfer_stats == want
 
 
