@@ -3,7 +3,7 @@ export PYTHONPATH
 
 .PHONY: test properties smoke smoke-router smoke-chunked smoke-steal \
 	smoke-quant smoke-elastic smoke-prefix smoke-fleet-prefix \
-	smoke-autotune perf-gate bench ci
+	smoke-autotune ci
 
 test:
 	python -m pytest -x -q
@@ -86,24 +86,14 @@ smoke-fleet-prefix:
 	    --prefix-cache 16 --replicas 2 --verify-fleet-prefix
 
 # self-tuning-knob smoke (PR 9): serve with --prefill-chunk auto — the
-# analytic perf model (seeded from the bench's published calibration
-# when results/BENCH_serving.json is present) picks the chunk at the
-# per-bucket efficiency knee — and assert the chosen chunk sits on the
-# ladder at or below the bench-measured knee, with outputs
+# analytic perf model picks the chunk at the per-bucket efficiency knee
+# — and assert the chosen chunk sits on the ladder, with outputs
 # token-identical to a hand-set reference chunk
 smoke-autotune:
 	python -m repro.launch.serve --arch deepseek-7b --smoke \
 	    --requests 8 --new-tokens 4 --slots 2 --max-len 64 \
 	    --prefill-chunk auto --verify-autotune
 
-# perf-regression gate: named deterministic scenarios vs the bounds in
-# results/PERF_REFERENCES.json — exits 1 loudly on any violation
-perf-gate:
-	python benchmarks/perf_gate.py
-
-bench:
-	python -m benchmarks.run --only serving
-
 ci: test properties smoke smoke-router smoke-chunked smoke-steal \
 	smoke-quant smoke-elastic smoke-prefix smoke-fleet-prefix \
-	smoke-autotune perf-gate bench
+	smoke-autotune

@@ -14,8 +14,9 @@ closed loop, end to end:
 4. the trough then shrinks the fleet back down (EWMA-smoothed
    sustained-underload hysteresis, so Poisson blips don't flap it).
 
-The punchline the perf gate (benchmarks/perf_gate.py) holds as a CI
-contract: at equal offered load the elastic fleet sheds LESS at the
+The punchline, held as a bound by
+``tests/test_controller.py::test_fleet_sim_scenario_within_bounds``:
+at equal offered load the elastic fleet sheds LESS at the
 peak than the fixed fleet AND burns fewer replica-seconds across the
 trough — and nothing is ever lost across any scale or fault event.
 
@@ -38,7 +39,7 @@ for d in ctl.decisions:
     print(f"  t={d.now:7.3f}s  {d.action:12s} replica={d.replica} "
           f"live={d.live}  [{d.reason}]")
 
-# -- the comparison the perf gate pins -------------------------------------
+# -- the comparison the elastic scenario bounds ---------------------------
 fx, el = r["fixed"], r["elastic"]
 print(f"\n{'':14s}{'fixed(4)':>10s}{'elastic(2..8)':>14s}")
 print(f"{'shed':14s}{fx['shed']:>10d}{el['shed']:>14d}")

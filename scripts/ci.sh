@@ -1,11 +1,9 @@
 #!/usr/bin/env bash
 # Tier-1 CI: test suite + property-based scheduler invariants + serving
-# smoke runs (single-engine and 2-replica router, both archs) + serving
-# benchmark JSON. The actual command lines live in the Makefile (single
-# source).
+# smoke runs (single-engine and 2-replica router, both archs). The actual
+# command lines live in the Makefile (single source).
 #
 #   scripts/ci.sh          # tests + properties + smokes
-#   scripts/ci.sh --bench  # also emit + validate results/BENCH_serving.json
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -42,20 +40,5 @@ make smoke-fleet-prefix
 
 echo "== autotune smoke: --prefill-chunk auto on the perf-model knee =="
 make smoke-autotune
-
-echo "== perf-regression gate (results/PERF_REFERENCES.json) =="
-make perf-gate
-
-if [[ "${1:-}" == "--bench" ]]; then
-    echo "== serving benchmark (results/BENCH_serving.json) =="
-    make bench
-    echo "== validate BENCH_serving.json schema =="
-    PYTHONPATH=src python -c "
-import json
-from benchmarks.bench_serving import JSON_PATH, validate_payload
-validate_payload(json.load(open(JSON_PATH)))
-print('schema OK:', JSON_PATH)
-"
-fi
 
 echo "CI OK"

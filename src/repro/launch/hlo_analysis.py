@@ -567,8 +567,7 @@ def analyze(text: str) -> HloSummary:
 # --------------------------------------------------------------------------
 from repro.core.backend import TPU_V5E, BackendSpec  # noqa: E402
 
-# Back-compat aliases: these used to be hardcoded literals here and are
-# imported by the roofline/fig7/table2 benches.
+# Aliases of the v5e spec's peak rates, per chip.
 PEAK_FLOPS_BF16 = TPU_V5E.peak_flops_bf16   # per chip
 HBM_BW = TPU_V5E.hbm_bw                     # per chip
 ICI_BW = TPU_V5E.ici_bw                     # per link

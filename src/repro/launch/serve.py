@@ -57,39 +57,13 @@ from repro.models import model as model_mod
 from repro.serving.engine import InferenceEngine, Request, make_replicas
 from repro.serving.router import ReplicaRouter
 
-# same greedy-token-agreement guardrail the serving bench asserts
-# (BENCH_serving.json quantized.agreement_threshold)
+# minimum greedy-token agreement of a w8a8 engine with fp32 on the same
+# trace (--verify-quant)
 QUANT_AGREEMENT_THRESHOLD = 0.90
 
 # the hand-set chunk the CI chunked smoke uses — the reference the
 # autotune smoke must be token-identical to
 AUTOTUNE_REF_CHUNK = 16
-BENCH_JSON = "results/BENCH_serving.json"
-
-
-def _autotune_model():
-    """(PerfModel, bench_knee) for the autotune smoke: the model is
-    seeded from the bench-published fitted dispatch-cost line
-    (``perf_model.fitted_terms`` in BENCH_serving.json) when the file is
-    present, so the smoke's auto chunk sits on the SAME measured
-    efficiency curve the bench knee was read from — which makes
-    ``chosen <= knee`` exact (a smaller ladder with a lower top bucket
-    has a lower knee threshold on the same curve), not a flaky
-    cross-measurement comparison.  Cold analytic defaults (and no knee
-    bound) when the bench file is missing."""
-    import json
-    from repro.serving.perf_model import PerfModel
-    pm, knee = PerfModel(), None
-    try:
-        with open(BENCH_JSON) as f:
-            sec = json.load(f)["perf_model"]
-        terms = sec["fitted_terms"]["chunk_prefill/fp32"]
-        pm.set_dispatch_cost("chunk_prefill", terms["t_fix_ms"] / 1e3,
-                             terms["t_tok_us"] / 1e6)
-        knee = int(sec["knee_bucket"])
-    except (OSError, KeyError, ValueError):
-        pass
-    return pm, knee
 
 
 def _lm_requests(args, cfg):
@@ -133,20 +107,9 @@ def serve_lm(args):
         precisions = [p.strip() for p in args.replica_precisions.split(",")] \
             if args.replica_precisions \
             else [args.precision] * args.replicas
-        router_kw = {}
-        if len(set(precisions)) > 1:
-            # mixed fleet: seed the router's cross-precision cost scaling
-            # from the bench-measured fp32/w8a8 fitted terms when the
-            # bench file is present (PerfModel falls back to the paper's
-            # SecV 2x-density constant otherwise)
-            from repro.serving.perf_model import PerfModel
-            pm = PerfModel()
-            pm.load_precision_scale(BENCH_JSON)
-            router_kw["perf_model"] = pm
         router = ReplicaRouter(make_replicas(cfg, params, args.replicas,
                                              precisions=precisions, **kw),
-                               route=args.route, steal=args.steal,
-                               **router_kw)
+                               route=args.route, steal=args.steal)
         if args.verify_steal:
             return _verify_steal(router, reqs, args)
         if args.verify_quant:
@@ -166,11 +129,11 @@ def serve_lm(args):
         raise SystemExit("--verify-steal needs --replicas >= 2 --steal")
     if args.replica_precisions:
         raise SystemExit("--replica-precisions needs --replicas >= 2")
-    bench_knee = None
     if args.verify_autotune:
         if args.prefill_chunk != "auto":
             raise SystemExit("--verify-autotune needs --prefill-chunk auto")
-        kw["perf_model"], bench_knee = _autotune_model()
+        from repro.serving.perf_model import PerfModel
+        kw["perf_model"] = PerfModel()
     eng = InferenceEngine(cfg, params, precision=args.precision, **kw)
     t0 = time.perf_counter()
     eng.run(reqs)
@@ -201,10 +164,6 @@ def serve_lm(args):
         if chosen not in eng.buckets:
             raise SystemExit(f"FAIL: auto chunk {chosen} is not on the "
                              f"bucket ladder {eng.buckets}")
-        if bench_knee is not None and chosen > bench_knee:
-            raise SystemExit(f"FAIL: auto chunk {chosen} above the "
-                             f"bench-measured efficiency knee "
-                             f"{bench_knee}")
         ref = InferenceEngine(cfg, params, precision=args.precision,
                               **dict(kw, perf_model=None,
                                      prefill_chunk=AUTOTUNE_REF_CHUNK))
@@ -216,10 +175,8 @@ def serve_lm(args):
             raise SystemExit(f"FAIL: auto-chunk outputs diverge from the "
                              f"hand-set chunk {AUTOTUNE_REF_CHUNK} for "
                              f"requests {bad}")
-        knee_note = (f"<= bench knee {bench_knee}" if bench_knee is not None
-                     else "no bench reference, analytic model")
         print(f"verify-autotune OK: auto chunk {chosen} on ladder "
-              f"{eng.buckets} ({knee_note}); {len(reqs)} requests "
+              f"{eng.buckets}; {len(reqs)} requests "
               f"token-identical to hand-set chunk {AUTOTUNE_REF_CHUNK}")
     if args.verify_quant:
         if args.precision != "w8a8":
@@ -559,8 +516,7 @@ def main(argv=None):
                          "chunked outputs are token-identical")
     ap.add_argument("--verify-autotune", action="store_true",
                     help="with --prefill-chunk auto: assert the chosen "
-                         "chunk is on the bucket ladder, within the "
-                         "bench-measured efficiency knee, and "
+                         "chunk is on the bucket ladder and "
                          "token-identical to the hand-set default "
                          "(the CI autotune smoke)")
     ap.add_argument("--prefix-cache", type=int, default=None,

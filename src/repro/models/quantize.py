@@ -125,7 +125,7 @@ def materialize(params, schemes: Dict[str, str],
 
 
 def default_calib_tokens(cfg: ModelConfig, batch: int = 2, seq: int = 16):
-    """Deterministic calibration batch (the bench/tests replay the same)."""
+    """Deterministic calibration batch (the tests replay the same)."""
     return jax.random.randint(jax.random.PRNGKey(0), (batch, seq), 0,
                               cfg.vocab_size, dtype=jnp.int32)
 

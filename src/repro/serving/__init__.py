@@ -10,7 +10,7 @@
   caches (T5 bucketing).
 - ``telemetry.py``  — shared stats surface: QPS, p50/p95/p99 latency,
   queue depth, SLA-miss fraction, compile counts, per-stage dispatches;
-  consumed by launch/serve.py, the examples, and benchmarks.
+  consumed by launch/serve.py, the examples, and bench/run.py.
 - ``engine.py``     — LM engine: continuous slot-batched greedy decode
   with bucketed **batched prefill** (freed slots refill together in one
   bucketed call) and **chunked prefill** for every block pattern on the
@@ -38,7 +38,8 @@
 - ``fleet_sim.py``  — deterministic discrete-event fleet simulator
   (virtual clock, per-replica service times, seeded arrivals) behind
   the REAL router; drives the work-stealing property suite
-  (tests/fleet_sim.py) and the bench's ``work_stealing`` section.
+  (tests/fleet_sim.py), the fleet-sim scenario tests, and the
+  elastic smoke.
 
 The N-stage software-pipeline driver itself lives in
 ``repro/core/pipeline.py`` (paper T2, Fig. 6 generalized).

@@ -27,10 +27,9 @@ trace generators (``diurnal_trace`` / ``flash_crowd_trace`` /
 ``hot_burst_trace`` / ``multi_tenant_trace``) and the ``run_elastic``
 driver push 10^5+ seeded arrivals through the closed control loop.
 
-Used by ``tests/fleet_sim.py`` (the property-suite harness),
-``benchmarks/bench_serving.py`` (``work_stealing`` + ``elastic``
-sections), and ``benchmarks/perf_gate.py`` (the CI perf-regression
-gate's scenarios).
+Used by ``tests/fleet_sim.py`` (the property-suite harness), the
+steal / router / elastic scenario bounds in ``tests/test_controller.py``,
+``launch/serve.py --elastic-smoke`` and ``examples/serve_elastic.py``.
 """
 from __future__ import annotations
 
@@ -658,13 +657,12 @@ def elastic_vs_fixed(n: int = 4_000, *, base_gap_s: float = 0.006,
                      kills: Sequence[Tuple[float, int]] = (),
                      kill_at_frac: Optional[float] = None,
                      kill_idx: int = 0) -> dict:
-    """The elastic-fleet headline scenario (bench ``elastic`` section +
-    perf-gate ``elastic`` scenario): the SAME seeded flash-crowd trace
+    """The elastic-fleet headline scenario: the SAME seeded flash-crowd trace
     through (a) a fixed mid-sized fleet and (b) an autoscaled fleet
     under a FleetController. The elastic fleet must shed less at the
     peak (it can grow past the fixed size) AND burn fewer
     replica-seconds over the run (it shrinks through the trough) —
-    both bit-deterministic, so the perf gate can hold tight thresholds.
+    both bit-deterministic, so a test can hold tight bounds on them.
     """
     from repro.runtime.fault_tolerance import HeartbeatMonitor
     from repro.serving.controller import ControllerConfig, FleetController

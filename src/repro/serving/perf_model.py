@@ -35,11 +35,10 @@ Unmeasured, the model falls back to an analytic default line (overhead
 answers sharpen as ``observe()`` feeds real dispatch timings.  All fits
 are medians + least squares over the stored samples — same samples in,
 same fitted terms and same suggestions out (calibration is
-deterministic; the bench and the property suite both pin this).
+deterministic; tests/test_perf_model.py pins this).
 """
 from __future__ import annotations
 
-import json
 import math
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
@@ -84,13 +83,8 @@ class PerfModel:
         # (stage, bucket, batch, precision) -> measured dispatch seconds
         self._samples: Dict[Tuple[str, int, int, str], List[float]] = {}
         # (stage, precision) -> (t_fix_s, t_tok_s) pinned directly via
-        # set_dispatch_cost (reloaded published calibration)
+        # set_dispatch_cost
         self._fixed: Dict[Tuple[str, str], Tuple[float, float]] = {}
-        # precision -> measured step-time multiplier vs fp32, pinned via
-        # set_precision_scale / load_precision_scale (PR 10): replaces
-        # the spec's hard-coded §V constant once real fp32-vs-int8
-        # timings exist
-        self._precision_scale: Dict[str, float] = {}
 
     @classmethod
     def for_params(cls, params, *, device=None) -> "PerfModel":
@@ -135,8 +129,7 @@ class PerfModel:
 
     def set_dispatch_cost(self, stage: str, t_fix_s: float, t_tok_s: float,
                           *, precision: str = "fp32") -> None:
-        """Pin a stage's fitted line directly — e.g. reload the bench's
-        published calibration (``fitted_terms``) instead of re-measuring.
+        """Pin a stage's fitted line directly instead of measuring it.
         A pinned line takes precedence over stored samples."""
         self._fixed[(stage, precision)] = (float(t_fix_s), float(t_tok_s))
 
@@ -223,27 +216,14 @@ class PerfModel:
         The router's scale-up seed uses this to re-price a joiner whose
         precision differs from the measured fleet.
 
-        Resolution ladder (PR 10): a pinned measured scale
-        (``set_precision_scale`` / ``load_precision_scale``) wins; next a
-        ratio FITTED from this model's own samples — the marginal-token
-        ratio of stages measured at BOTH precisions
-        (``fit_precision_scale``); finally the spec's hard-coded §V
-        constant (1.0 fp32, 0.5 on a 2x-int8 part)."""
-        pinned = self._precision_scale.get(precision)
-        if pinned is not None:
-            return pinned
+        Resolution ladder: a ratio FITTED from this model's own samples
+        — the whole-dispatch-cost ratio of stages measured at BOTH
+        precisions (``fit_precision_scale``); else the spec's hard-coded
+        §V constant (1.0 fp32, 0.5 on a 2x-int8 part)."""
         fitted = self.fit_precision_scale(precision)
         if fitted is not None:
             return fitted
         return self.spec.precision_scale(precision)
-
-    def set_precision_scale(self, precision: str, scale: float) -> None:
-        """Pin a measured precision multiplier (vs fp32). Overrides both
-        the fitted ratio and the spec constant."""
-        if scale <= 0.0:
-            raise ValueError(f"precision scale must be positive, "
-                             f"got {scale}")
-        self._precision_scale[precision] = float(scale)
 
     def fit_precision_scale(self, precision: str, *,
                             base: str = "fp32") -> Optional[float]:
@@ -281,43 +261,6 @@ class PerfModel:
             if cost_b > 0.0:
                 ratios.append((fix_p + n * tok_p) / cost_b)
         return _median(ratios) if ratios else None
-
-    def load_precision_scale(self, path: str, *, precision: str = "w8a8",
-                             base: str = "fp32") -> Optional[float]:
-        """Pin ``precision``'s multiplier from the published bench JSON's
-        measured fitted terms (``perf_model.fitted_terms``): the median
-        whole-dispatch-cost ratio at ``_SCALE_REF_TOKENS`` tokens across
-        stages the bench calibrated at both precisions (same robust
-        ratio as ``fit_precision_scale`` — raw slope ratios blow up
-        when a fit degenerates). Returns the pinned scale, or None —
-        bench JSON absent, unreadable, or missing a both-precision
-        stage — in which case nothing is pinned and ``precision_scale``
-        keeps the spec constant."""
-        try:
-            with open(path) as f:
-                terms = json.load(f)["perf_model"]["fitted_terms"]
-            ratios = []
-            n = _SCALE_REF_TOKENS
-            for name in sorted(terms):
-                stage, _, prec = name.rpartition("/")
-                if prec != precision:
-                    continue
-                b = terms.get(f"{stage}/{base}")
-                if b is None:
-                    continue
-                cost_b = (float(b["t_fix_ms"]) * 1e-3
-                          + n * float(b["t_tok_us"]) * 1e-6)
-                cost_p = (float(terms[name]["t_fix_ms"]) * 1e-3
-                          + n * float(terms[name]["t_tok_us"]) * 1e-6)
-                if cost_b > 0.0:
-                    ratios.append(cost_p / cost_b)
-        except (OSError, KeyError, TypeError, ValueError):
-            return None
-        if not ratios:
-            return None
-        scale = _median(ratios)
-        self.set_precision_scale(precision, scale)
-        return scale
 
     # ---- transfer terms --------------------------------------------------
     def transfer_s(self, *, h2d_bytes: float = 0.0,
@@ -411,8 +354,8 @@ class PerfModel:
 
     # ---- reporting -------------------------------------------------------
     def fitted_terms(self) -> Dict[str, Dict[str, float]]:
-        """Fitted ``(t_fix, t_tok)`` per measured (stage, precision) —
-        the bench's record of what calibration produced."""
+        """Fitted ``(t_fix, t_tok)`` per measured (stage, precision): the
+        record of what calibration produced."""
         out: Dict[str, Dict[str, float]] = {}
         for stage, prec in sorted({(st, p)
                                    for (st, _, _, p) in self._samples}

@@ -167,8 +167,8 @@ class Telemetry:
         """``n`` remote hits where shipping the holder's snapshot was
         priced ABOVE recomputing the prefix (short prefix, byte-heavy
         state): this replica recomputes the prefill instead. The other
-        leg of the restore-vs-recompute decision — counted so the bench
-        can show the decision fires in both directions."""
+        leg of the restore-vs-recompute decision — counted so the report
+        shows the decision fires in both directions."""
         self.prefix_recomputed += n
 
     def record_prefix_host_hit(self, n: int = 1):
@@ -339,7 +339,7 @@ class Telemetry:
         return out
 
     def summary(self) -> Dict[str, float]:
-        """Flat dict for JSON emission (benchmarks/BENCH_serving.json)."""
+        """Flat dict of the counters and latency tails, for JSON."""
         out = {"served": self.served, "qps": self.qps(),
                "steps": self.steps, "prefills": self.prefills,
                "prefill_batches": self.prefill_batches,

@@ -42,7 +42,7 @@ class ShardingRules:
         return dataclasses.replace(self, **kw)
 
 
-# Presets used by the benchmarks / hillclimb
+# Presets for the dry-run's ``--preset``
 BASELINE_RULES = ShardingRules()
 FSDP_RULES = ShardingRules(embed="data")          # training: params over data
 REPLICATED_ATTN = ShardingRules(heads=None, kv_heads=None)

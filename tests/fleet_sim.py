@@ -1,7 +1,7 @@
 """Fleet-simulation property harness (PR 4 work stealing).
 
 The simulator core lives in ``repro.serving.fleet_sim`` (it is runtime
-infrastructure: the bench's ``work_stealing`` section runs it too); this
+infrastructure: the elastic smoke and example run it too); this
 module is the test-facing surface — re-exports plus the seeded
 random-schedule driver the property suite uses to push the fleet through
 thousands of submit / steal / fail / complete interleavings with zero

@@ -7,6 +7,7 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
+from repro.configs import DLRM_CONFIGS, dlrm_paper
 from repro.core import bucketing as B
 from repro.core import host_split as HS
 from repro.core import metrics as MET
@@ -35,13 +36,31 @@ def test_partition_assigns_every_table_once(n, shards, seed):
         assert asn.table_offset[t] + rows[t] <= hi
 
 
-def test_length_aware_beats_naive_on_skew():
-    """Paper §VI-B: 15-34% SLS latency reduction with length info. Skewed
-    workload: big tables with few lookups, small hot tables."""
+def _skewed_tables():
+    """Big tables with few lookups, small hot tables."""
     rng = np.random.default_rng(7)
     rows = [10_000_000] * 8 + [10_000] * 24
     looks = [1] * 8 + list(rng.integers(40, 80, 24))
-    rep = PT.balance_report(rows, looks, num_shards=6)
+    return rows, looks, 1
+
+
+def _paper_tables(name):
+    cfg = DLRM_CONFIGS[name]
+    return cfg.table_rows, cfg.avg_lookups_per_table, cfg.embed_dim
+
+
+@pytest.mark.parametrize("tables,shards", [
+    ("skewed", 6),
+    *[(name, shards) for name in sorted(DLRM_CONFIGS)
+      for shards in (6, 16, 32)],
+])
+def test_length_aware_beats_naive_on_skew(tables, shards):
+    """Paper §VI-B: 15-34% SLS latency reduction with length info, on a
+    skewed workload and on the paper's two DLRM configs at the paper's
+    6-card system and at mesh scales."""
+    rows, looks, dim = (_skewed_tables() if tables == "skewed"
+                        else _paper_tables(tables))
+    rep = PT.balance_report(rows, looks, shards, dim)
     assert rep["latency_reduction"] > 0.15, rep
     assert rep["aware_imbalance"] < rep["naive_imbalance"]
 
@@ -111,12 +130,38 @@ def test_partial_transfer_roundtrip(rng):
     assert stats.num_transfers_batched < stats.num_transfers_naive
 
 
-def test_partial_transfer_saves_most_bytes_when_sparse(rng):
+def _one_lookup_bags(rng):
     bags = [[[1] for _ in range(16)] for _ in range(8)]   # 1 of 64 slots used
-    sb = TR.pack_sparse_inputs(bags, num_tables=16, max_lookups=64)
+    return TR.pack_sparse_inputs(bags, num_tables=16, max_lookups=64)
+
+
+def _paper_complex_bags(rng, batch=64):
+    """PAPER_COMPLEX's index shape (96 tables x 128 max lookups) with
+    Poisson bags around its per-table average lookups."""
+    cfg = dlrm_paper.PAPER_COMPLEX
+    T, L = cfg.num_tables, cfg.max_lookups_per_table
+    avg = np.asarray(cfg.avg_lookups_per_table)
+    lengths = np.minimum(rng.poisson(avg[None, :], (batch, T)) + 1,
+                         L).astype(np.int32)
+    indices = np.zeros((batch, T, L), np.int32)
+    for t in range(T):
+        k = int(lengths[:, t].max())
+        indices[:, t, :k] = rng.integers(0, 10_000, (batch, k))
+    return TR.SparseBatch(indices, lengths)
+
+
+@pytest.mark.parametrize("make_batch,min_saved", [
+    (_one_lookup_bags, 0.9), (_paper_complex_bags, 0.7)],
+    ids=["one-lookup", "paper-complex"])
+def test_partial_transfer_saves_most_bytes_when_sparse(rng, make_batch,
+                                                       min_saved):
+    sb = make_batch(rng)
     stats = TR.TransferStats()
     TR.command_batched_transfer(sb, stats)
-    assert stats.bytes_saved_frac > 0.9
+    assert stats.bytes_saved_frac > min_saved
+    # command batching: staged ids + lengths against one put per table
+    assert stats.num_transfers_batched == 2
+    assert stats.num_transfers_naive == sb.indices.shape[1] + 1
 
 
 # ---- host split ----------------------------------------------------------

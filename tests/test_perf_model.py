@@ -4,7 +4,6 @@ calibration, the three self-tuning knobs (auto prefill chunk, suggested
 bucket ladder, cold-start service priors), the ServiceEstimator
 cold-start precedence, the router's per-precision EWMA scale-up seed,
 and the backend-spec parameterization of the roofline terms."""
-import json
 import statistics
 
 import pytest
@@ -47,8 +46,8 @@ def test_percentile_boundary_semantics():
 
 def test_percentile_interpolates_between_ranks():
     # p99 of 100 samples: rank 99*0.99 = 98.01 -> 1% of the gap to the
-    # top sample (the chunked-prefill bench's long-prompt outlier gets
-    # 1% weight, not zero and not full)
+    # top sample (a long-prompt outlier gets 1% weight, not zero and
+    # not full)
     vals = [float(v) for v in range(100)]
     vals[99] = 1000.0
     assert percentile(vals, 0.99) == pytest.approx(98 + 0.01 * (1000 - 98))
@@ -81,7 +80,7 @@ def test_fit_recovers_the_measured_line():
 
 def test_calibration_is_deterministic():
     """Same samples in -> identical fitted terms and identical knob
-    suggestions out (the bench and the smoke both rely on this)."""
+    suggestions out (the autotune smoke relies on this)."""
     a, b = _fed_model(), _fed_model()
     assert a.fit_dispatch_cost("chunk_prefill") == \
         b.fit_dispatch_cost("chunk_prefill")
@@ -96,13 +95,12 @@ def test_cold_model_knees_from_the_default_line():
     """Unmeasured, the knee comes from the analytic default line
     (t_fix = DEFAULT_FIX_TOKENS marginal tokens): e(b) = b/(b+24), so
     the 0.75-of-top threshold lands at 32 on the smoke ladder and 64 on
-    the bench ladder — the values the hand-set knobs used."""
+    a (16, 64, 448) ladder — the values the hand-set knobs used."""
     pm = PerfModel(1e9)
     assert pm.suggest_prefill_chunk((16, 32, 64)) == 32
     assert pm.suggest_prefill_chunk((16, 64, 448)) == 64
     # efficiency is monotone, so a ladder with a LOWER top bucket can
-    # only knee at or below a taller ladder's knee (the smoke's
-    # chosen-chunk <= bench-knee assertion is a theorem, not a race)
+    # only knee at or below a taller ladder's knee
     assert pm.suggest_prefill_chunk((16, 32, 64)) <= \
         pm.suggest_prefill_chunk((16, 64, 448))
 
@@ -179,7 +177,7 @@ def test_fit_precision_scale_is_the_whole_cost_ratio():
 
 
 def test_fit_precision_scale_survives_a_degenerate_base_fit():
-    """The bench regression this guards: two near-equal calibration
+    """The regression this guards: two near-equal calibration
     buckets can degenerate least-squares so the base slope clamps to
     epsilon with all cost pushed into t_fix.  The raw slope ratio then
     explodes by ~9 orders of magnitude; the whole-dispatch-cost ratio
@@ -193,39 +191,6 @@ def test_fit_precision_scale_survives_a_degenerate_base_fit():
     got = pm.fit_precision_scale("w8a8")
     assert got == pytest.approx(want, rel=1e-9)
     assert got < 100.0                        # slope ratio would be ~1.4e9
-
-
-def test_load_precision_scale_pins_from_bench_terms(tmp_path):
-    """The serve-time path: the published fitted_terms (ms/us units)
-    pin the multiplier; absent or malformed JSON pins nothing and the
-    spec constant survives."""
-    # w8a8 terms at exactly 0.25x the fp32 whole cost (distinguishable
-    # from the 0.5 spec constant); the decode/fp32 orphan is skipped
-    path = tmp_path / "bench.json"
-    path.write_text(json.dumps({"perf_model": {"fitted_terms": {
-        "chunk_prefill/fp32": {"t_fix_ms": 2.0, "t_tok_us": 10.0},
-        "chunk_prefill/w8a8": {"t_fix_ms": 0.5, "t_tok_us": 2.5},
-        "decode/fp32": {"t_fix_ms": 1.0, "t_tok_us": 4.0},
-    }}}))
-    pm = PerfModel(1e9)
-    assert pm.load_precision_scale(str(path)) == pytest.approx(0.25)
-    assert pm.precision_scale("w8a8") == pytest.approx(0.25)
-    # fit_dispatch_cost's cross-precision fallback stays on the SPEC
-    # ratio by design (avoids circularity with the fitted scale)
-    pm.set_dispatch_cost("prefill", 4e-3, 8e-6)
-    w8 = pm.fit_dispatch_cost("prefill", precision="w8a8")
-    assert w8[0] == pytest.approx(4e-3 * 0.5)
-
-    for bad in ("missing.json", "junk.json", "no_pair.json"):
-        pm_bad = PerfModel(1e9)
-        if bad == "junk.json":
-            (tmp_path / bad).write_text("{not json")
-        elif bad == "no_pair.json":
-            (tmp_path / bad).write_text(json.dumps({"perf_model": {
-                "fitted_terms": {"decode/w8a8": {"t_fix_ms": 1.0,
-                                                 "t_tok_us": 1.0}}}}))
-        assert pm_bad.load_precision_scale(str(tmp_path / bad)) is None
-        assert pm_bad.precision_scale("w8a8") == pytest.approx(0.5)
 
 
 def test_transfer_terms_carry_the_h2d_d2h_asymmetry():

@@ -183,64 +183,3 @@ def test_build_on_siteless_arch_is_empty_not_an_error():
     qp = build_quantized_params(cfg, params)
     assert qp.quantized_sites == 0 and qp.fallback_sites == 0
     assert not qp.schemes
-
-
-# ---- BENCH_quant.json schema ----------------------------------------------
-
-def _fake_quant_payload():
-    return {
-        "dlrm_embed": {
-            "budget": 5e-4,
-            "int8": {"ne_delta": 1e-5, "within_budget": True},
-            "int4": {"ne_delta": 2e-4, "within_budget": True},
-        },
-        "workflow": {"passed": True, "ne_delta": 1e-5, "budget": 5e-4,
-                     "iterations": 1, "fp16_fallbacks": 0,
-                     "fallback_layers": []},
-        "mixed48": {"ne_delta": 1e-4, "within_budget": True, "budget": 5e-4,
-                    "int4_tables": 3, "num_tables": 4, "upgrades": 1,
-                    "bytes_vs_int8": 0.6},
-        "backbone": {"arch": "gemma-2b", "cosine": 0.999,
-                     "requirement": 0.98, "within": True},
-        "w8a8_build": {"arch": "deepseek-7b", "budget": 0.05,
-                       "quantized_sites": 7, "fallback_sites": 0,
-                       "fallback_names": [], "calib_disagreement": 0.0,
-                       "within_budget": True},
-    }
-
-
-def test_bench_quant_schema_accepts_complete_payload():
-    from benchmarks.bench_quant import validate_payload
-    validate_payload(_fake_quant_payload())
-
-
-def test_bench_quant_schema_rejects_missing_keys():
-    from benchmarks.bench_quant import validate_payload
-    p = _fake_quant_payload()
-    del p["w8a8_build"]["calib_disagreement"]
-    del p["dlrm_embed"]["int8"]["ne_delta"]
-    del p["backbone"]
-    with pytest.raises(ValueError) as ei:
-        validate_payload(p)
-    msg = str(ei.value)
-    assert "w8a8_build.calib_disagreement" in msg
-    assert "dlrm_embed.int8.ne_delta" in msg
-    assert "backbone" in msg
-
-
-def test_bench_quant_emit_round_trips(tmp_path):
-    import json
-    from benchmarks.bench_quant import emit
-    path = tmp_path / "BENCH_quant.json"
-    emit(_fake_quant_payload(), path=str(path))
-    assert json.loads(path.read_text()) == _fake_quant_payload()
-
-
-def test_bench_quant_emit_unwritable_exits_nonzero(tmp_path, capsys):
-    from benchmarks.bench_quant import emit
-    blocker = tmp_path / "not-a-dir"
-    blocker.write_text("file, not a directory")
-    with pytest.raises(SystemExit) as ei:
-        emit(_fake_quant_payload(), path=str(blocker / "BENCH_quant.json"))
-    assert ei.value.code == 1
-    assert "cannot write" in capsys.readouterr().err

@@ -1,8 +1,5 @@
 """ReplicaRouter: routing rule, fleet telemetry aggregation, concurrent-
-drain semantics, priority/shedding through real engines, and the
-BENCH_serving.json schema/writability contract."""
-import json
-
+drain semantics, and priority/shedding through real engines."""
 import jax
 import numpy as np
 import pytest
@@ -562,217 +559,3 @@ def test_priority_and_shedding_through_lm_engine(lm_setup):
     assert eng.telemetry.served == len(served)
     assert eng.telemetry.prefills == len(served)   # shed never prefilled
     assert eng.telemetry.shed == sum(t.shed for t in tickets)
-
-
-# ---- BENCH_serving.json contract (satellite) ------------------------------
-
-def _fake_summary():
-    t = Telemetry()
-    t.record_latency(10.0, False)
-    return t.summary()
-
-
-def _fake_payload():
-    fleet = dict(_fake_summary(), replicas=1, routed_per_replica=[1])
-    cls = {"total": 1, "served": 1, "shed": 0, "sla_attainment": 1.0}
-    return {"lm": _fake_summary(),
-            "dlrm": dict(_fake_summary(), transfer_bytes_saved_frac=0.5),
-            "router": {"offered_load": 1, "slo_ms": 1.0, "single": fleet,
-                       "dual": fleet, "p99_improved": True,
-                       "misses_improved": True},
-            "overload": {"service_ms_est": 1.0, "high": cls, "low": cls},
-            "chunked_prefill": {"arch": "a", "offered_load_ms": 1.0,
-                                "requests": 1,
-                                "long_tokens": 1, "prefill_chunk": 1,
-                                "monolithic": _fake_summary(),
-                                "chunked": _fake_summary(),
-                                "ttft_p99_improved": True,
-                                "stateful": {
-                                    "arch": "b", "requests": 1,
-                                    "prefill_chunk": 1,
-                                    "monolithic": _fake_summary(),
-                                    "chunked": _fake_summary(),
-                                    "token_identical": True}},
-            "work_stealing": {"requests": 1, "replicas": 2, "skew": 0.5,
-                              "steal": _fake_summary(),
-                              "no_steal": _fake_summary(),
-                              "served_per_replica_steal": [1, 0],
-                              "served_per_replica_no_steal": [1, 0],
-                              "spread_steal": 0, "spread_no_steal": 1,
-                              "p99_improved": True,
-                              "spread_improved": True},
-            "elastic": {"requests": 1, "fixed_replicas": 4,
-                        "initial_replicas": 2, "max_replicas": 8,
-                        "fixed": _fake_summary(),
-                        "elastic": _fake_summary(),
-                        "controller": {"scale_ups": 1, "scale_downs": 1,
-                                       "faults_drained": 0},
-                        "shed_fixed": 2, "shed_elastic": 1,
-                        "shed_improved": True,
-                        "replica_seconds_fixed": 2.0,
-                        "replica_seconds_elastic": 1.0,
-                        "capacity_improved": True,
-                        "trough_live_mean": 2.0, "zero_lost": True},
-            "quantized": {"arch": "a", "budget": 0.05,
-                          "calib_disagreement": 0.0,
-                          "quantized_sites": 7, "fallback_sites": 0,
-                          "token_agreement": 1.0,
-                          "agreement_threshold": 0.9,
-                          "agreement_ok": True, "logit_rel_err": 0.01,
-                          "fp32": _fake_summary(),
-                          "w8a8": _fake_summary(),
-                          "fleet": {"replicas": 2,
-                                    "precisions": ["fp32", "w8a8"],
-                                    "routed_per_replica": [1, 1],
-                                    "high_on_fp32": True,
-                                    "zero_lost": True,
-                                    "precision_rehomed": 0},
-                          "speed_ratio_model": 0.5,
-                          "decode_throughput_fp32": 1.0,
-                          "decode_throughput_w8a8": 2.0,
-                          "decode_throughput_improved": True,
-                          "ttft_ms_p99_fp32": 1.0,
-                          "ttft_ms_p99_w8a8": 0.5,
-                          "ttft_p99_no_worse": True},
-            "prefix_cache": {"arch": "a", "requests": 1,
-                             "prefix_tokens": 256, "prefill_chunk": 64,
-                             "offered_load_ms": 1.0,
-                             "cold": _fake_summary(),
-                             "hit": _fake_summary(),
-                             "ttft_hit_ratio": 0.5,
-                             "ttft_hit_improved": True,
-                             "token_identical": True, "prefix_hits": 1},
-            "fleet_prefix": {"arch": "a", "replicas": 2, "families": 5,
-                             "requests": 36, "prefix_tokens": 256,
-                             "prefill_chunk": 16, "offered_load_ms": 1.0,
-                             "cold": _fake_summary(),
-                             "per_engine": _fake_summary(),
-                             "shared": _fake_summary(),
-                             "ttft_hit_ratio": 0.1,
-                             "ttft_fleet_improved": True,
-                             "token_identical": True, "zero_lost": True,
-                             "prefix_remote_hits": 2, "prefix_shipped": 1,
-                             "prefix_recomputed": 1,
-                             "host_tier": {"entries": 4, "evicted_into": 0,
-                                           "host_hits": 0,
-                                           "drain_fault_ins": 1},
-                             "pricing": {"ship": {"arch": "a", "shipped": 1,
-                                                  "recomputed": 0,
-                                                  "remote_hits": 1},
-                                         "recompute": {"arch": "b",
-                                                       "shipped": 0,
-                                                       "recomputed": 1,
-                                                       "remote_hits": 1}}},
-            "paging": {"arch": "a", "sessions": 6, "slots": 2,
-                       "reference_slots": 6, "paged": _fake_summary(),
-                       "reference": _fake_summary(),
-                       "token_identical": True, "zero_lost": True,
-                       "paged_out": 1, "paged_in": 1,
-                       "partition_ok": True},
-            "perf_model": {"arch": "a", "flops_per_token": 1.0,
-                           "error_bound": 0.35, "max_rel_error": 0.1,
-                           "within_bound": True,
-                           "scenarios": [{"stage": "prefill", "tokens": 16,
-                                          "predicted_ms": 1.0,
-                                          "measured_ms": 1.0,
-                                          "rel_err": 0.0,
-                                          "overhead": 2.0}],
-                           "fitted_terms": {"chunk_prefill/fp32":
-                                            {"t_fix_ms": 1.0,
-                                             "t_tok_us": 10.0}},
-                           "knee_bucket": 64, "cold_knee_bucket": 32,
-                           "auto_prefill_chunk": 64, "hand_set_chunk": 16,
-                           "suggested_buckets": [16, 64],
-                           "cold_prior": {"bucket": 448, "base": 16,
-                                          "model_ratio": 6.0,
-                                          "linear_ratio": 28.0},
-                           "transfer": {"bytes_per_transfer": 1.0,
-                                        "d2h_s": 1.0, "h2d_s": 1.0,
-                                        "d2h_h2d_ratio": 2.9,
-                                        "bytes_saved_frac": 0.4}}}
-
-
-def test_bench_payload_schema_validates():
-    from benchmarks.bench_serving import validate_payload
-    validate_payload(_fake_payload())       # telemetry summary == schema
-
-
-def test_bench_payload_schema_rejects_missing_keys():
-    from benchmarks.bench_serving import validate_payload
-    p = _fake_payload()
-    del p["router"]["single"]["latency_ms_p99"]
-    del p["overload"]["high"]["sla_attainment"]
-    del p["chunked_prefill"]["chunked"]["ttft_ms_p99"]
-    del p["chunked_prefill"]["arch"]
-    del p["chunked_prefill"]["stateful"]["token_identical"]
-    del p["chunked_prefill"]["stateful"]["chunked"]["served"]
-    del p["work_stealing"]["steal"]["steals"]
-    del p["work_stealing"]["spread_improved"]
-    del p["quantized"]["token_agreement"]
-    del p["quantized"]["w8a8"]["precision_rehomed"]
-    del p["quantized"]["fleet"]["high_on_fp32"]
-    del p["elastic"]["shed_improved"]
-    del p["elastic"]["elastic"]["scaled_in"]
-    del p["elastic"]["controller"]["faults_drained"]
-    del p["prefix_cache"]["ttft_hit_ratio"]
-    del p["prefix_cache"]["hit"]["prefix_hits"]
-    del p["fleet_prefix"]["ttft_hit_ratio"]
-    del p["fleet_prefix"]["shared"]["prefix_remote_hits"]
-    del p["fleet_prefix"]["pricing"]["ship"]["shipped"]
-    del p["paging"]["partition_ok"]
-    del p["paging"]["paged"]["paged_out"]
-    del p["perf_model"]["max_rel_error"]
-    del p["perf_model"]["fitted_terms"]["chunk_prefill/fp32"]
-    del p["perf_model"]["scenarios"][0]["rel_err"]
-    del p["perf_model"]["transfer"]["d2h_h2d_ratio"]
-    with pytest.raises(ValueError) as ei:
-        validate_payload(p)
-    msg = str(ei.value)
-    assert "router.single.latency_ms_p99" in msg
-    assert "overload.high.sla_attainment" in msg
-    assert "chunked_prefill.chunked.ttft_ms_p99" in msg
-    assert "chunked_prefill.arch" in msg
-    assert "chunked_prefill.stateful.token_identical" in msg
-    assert "chunked_prefill.stateful.chunked.served" in msg
-    assert "work_stealing.steal.steals" in msg
-    assert "work_stealing.spread_improved" in msg
-    assert "quantized.token_agreement" in msg
-    assert "quantized.w8a8.precision_rehomed" in msg
-    assert "quantized.fleet.high_on_fp32" in msg
-    assert "elastic.shed_improved" in msg
-    assert "elastic.elastic.scaled_in" in msg
-    assert "elastic.controller.faults_drained" in msg
-    assert "prefix_cache.ttft_hit_ratio" in msg
-    assert "prefix_cache.hit.prefix_hits" in msg
-    assert "fleet_prefix.ttft_hit_ratio" in msg
-    assert "fleet_prefix.shared.prefix_remote_hits" in msg
-    assert "fleet_prefix.pricing.ship.shipped" in msg
-    assert "paging.partition_ok" in msg
-    assert "paging.paged.paged_out" in msg
-    assert "perf_model.max_rel_error" in msg
-    assert "perf_model.fitted_terms.chunk_prefill/fp32" in msg
-    assert "perf_model.scenarios[0].rel_err" in msg
-    assert "perf_model.transfer.d2h_h2d_ratio" in msg
-
-
-def test_bench_emit_writes_valid_json(tmp_path):
-    from benchmarks.bench_serving import emit, validate_payload
-    path = str(tmp_path / "BENCH_serving.json")
-    emit(_fake_payload(), path=path)
-    with open(path) as f:
-        validate_payload(json.load(f))
-
-
-def test_bench_emit_unwritable_results_exits_nonzero(tmp_path, capsys):
-    """The satellite fix: an unwritable results path must abort loudly
-    with a non-zero exit, not silently drop the JSON. A regular file
-    standing where the results dir should be fails makedirs/open with an
-    OSError for any uid (chmod tricks don't bite when tests run as
-    root)."""
-    from benchmarks.bench_serving import emit
-    blocker = tmp_path / "results"
-    blocker.write_text("not a directory")
-    with pytest.raises(SystemExit) as ei:
-        emit(_fake_payload(), path=str(blocker / "x.json"))
-    assert ei.value.code == 1
-    assert "cannot write" in capsys.readouterr().err

@@ -376,8 +376,8 @@ def test_chunked_run_deterministic(lm_setup):
 # archs/seeds/chunks; the bound leaves headroom for numerics drift
 # without masking a real regression.
 INT8_KV_CHUNK_AGREE = 0.95
-# w8a8 projections vs fp32: same greedy-token-agreement contract as the
-# bench guardrail (BENCH_serving.json quantized.agreement_threshold).
+# w8a8 projections vs fp32: same greedy-token-agreement contract as
+# launch/serve.py's --verify-quant (QUANT_AGREEMENT_THRESHOLD).
 W8A8_AGREE = 0.90
 
 
@@ -453,7 +453,7 @@ def test_int8_kv_chunked_survives_work_stealing():
 def test_w8a8_engine_agreement_bound(lm_setup):
     """Acceptance (PR 6): the w8a8 engine (per-channel int8 weights,
     dynamic per-row activation scales) matches fp32 greedy decoding
-    within the bench guardrail threshold, monolithic and chunked alike,
+    within the agreement threshold, monolithic and chunked alike,
     and its executables are cached under the precision-qualified key."""
     cfg, params = lm_setup
     kw = dict(batch_slots=3, max_len=64, prefill_buckets=(8, 16, 32, 48))
