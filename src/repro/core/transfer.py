@@ -6,19 +6,20 @@ but feature ingestion still crosses host->device. The paper's two tricks
 apply directly:
 
 - *Partial tensor transfers*: sparse-index tensors are compiled at a static
-  maximum size, but only each table's used prefix (its longest bag) is
-  transferred, padded to a width bucket.
+  maximum size, but only the lookups inside each bag are transferred,
+  padded to a width bucket.
 - *Command batching*: many small per-table index vectors are coalesced into
   one staging buffer and shipped as a single transfer.
 
-``staged_transfer`` ships the staging buffer, ``(B, W)`` with ``W`` a
-width bucket (the next power of two of the staged columns, at most
-``T * Lmax``); ``models/dlrm.py``'s ``sls_forward`` gathers straight from
-it. ``command_batched_transfer`` also unpacks it on the device into the
-static ``(B, T, Lmax)`` layout, one compiled program per shape and width
-bucket. The per-table used widths are data: both derive them from the
-lengths they are given, so requests with new bag lengths reuse what is
-compiled.
+``staged_transfer`` ships the staging buffer, ``(B, W)`` and item-major:
+row b holds item b's bags back to back in table order, then zeros, and
+``W`` is a width bucket (the next power of two of the longest item's
+lookups, at most ``T * Lmax``). ``models/dlrm.py``'s ``sls_forward``
+gathers straight from it. ``command_batched_transfer`` also unpacks it on
+the device into the static ``(B, T, Lmax)`` layout, one compiled program
+per shape and width bucket. Where each bag starts is data: both derive it
+from the lengths they are given, so requests with new bag lengths reuse
+what is compiled.
 """
 from __future__ import annotations
 
@@ -47,6 +48,11 @@ class TransferStats:
     def bytes_saved_frac(self) -> float:
         return 1.0 - self.bytes_partial / max(self.bytes_full, 1)
 
+    @property
+    def live_share(self) -> float:
+        """The share of the gathered index slots that are lookups."""
+        return self.rows_live / max(self.rows_gathered, 1)
+
 
 @dataclass
 class SparseBatch:
@@ -57,10 +63,6 @@ class SparseBatch:
     """
     indices: np.ndarray
     lengths: np.ndarray
-
-    @property
-    def used_per_table(self) -> np.ndarray:
-        return self.lengths.max(axis=0)      # (T,) max bag per table
 
 
 def pack_sparse_inputs(bags: Sequence[Sequence[Sequence[int]]],
@@ -91,50 +93,66 @@ def width_buckets(cap: int) -> Tuple[int, ...]:
 
 @functools.partial(jax.jit, static_argnames=("max_lookups",))
 def unpack_indices(staged, lengths, max_lookups: int):
-    """staged (B, W): each table's used columns, in table order, then
+    """staged (B, W): each item's bags back to back in table order, then
     padding; lengths (B, T) -> indices (B, T, max_lookups), zero past each
-    table's used width (the largest length of its bags)."""
-    used = lengths.max(axis=0)                              # (T,)
-    start = jnp.cumsum(used) - used
+    bag."""
+    B, T = lengths.shape
+    start = jnp.cumsum(lengths, axis=1) - lengths               # (B, T)
     pos = jnp.arange(max_lookups)
-    src = jnp.minimum(start[:, None] + pos[None, :], staged.shape[1] - 1)
-    vals = jnp.take(staged, src.reshape(-1), axis=1).reshape(
-        staged.shape[0], -1, max_lookups)
-    return jnp.where(pos[None, None, :] < used[None, :, None], vals, 0)
+    src = jnp.minimum(start[..., None] + pos, staged.shape[1] - 1)
+    vals = jnp.take_along_axis(staged, src.reshape(B, -1), axis=1)
+    return jnp.where(pos < lengths[..., None], vals.reshape(src.shape), 0)
+
+
+def _item_major(indices: np.ndarray, lengths: np.ndarray) -> np.ndarray:
+    """(B, T, L) bags -> (B, W) int32: each item's bags back to back in
+    table order, then zeros to the width bucket of the longest item. Row b
+    is T + 1 runs of consecutive reads, its bags and then its padding, so
+    slot k of the flat buffer reads ``k`` plus its run's (first read -
+    first slot)."""
+    B, T, L = indices.shape
+    totals = lengths.sum(axis=1)
+    width = staged_width(int(totals.max()), T * L)
+    runs = np.empty((B, T + 1), np.intp)
+    runs[:, :T] = lengths
+    runs[:, T] = width - totals
+    first = np.zeros((B, T + 1), np.intp)      # padding reads from 0 ...
+    first[:, :T] = np.arange(0, B * T * L, L).reshape(B, T)
+    runs = runs.ravel()
+    src = np.repeat(first.ravel() - (np.cumsum(runs) - runs), runs)
+    src += np.arange(B * width)
+    staged = indices.reshape(-1).take(src).reshape(B, width)
+    staged *= np.arange(width) < totals[:, None]            # ... and is zeroed
+    return staged.astype(np.int32, copy=False)
 
 
 def staged_transfer(batch: SparseBatch,
                     stats: Optional[TransferStats] = None,
                     device=None) -> Tuple[jax.Array, jax.Array]:
-    """Coalesce all tables' used index prefixes into ONE staging buffer and
-    make a single host->device put (command batching).
+    """Coalesce every bag's lookups into ONE staging buffer and make a
+    single host->device put (command batching).
 
-    Returns (staged (B, W) on device: each table's used columns in table
-    order, then zero padding to the width bucket; lengths (B, T) on
-    device).
+    Returns (staged (B, W) on device: row b holds item b's bags back to
+    back, table 0 first, then zeros to the width bucket of the longest
+    item; lengths (B, T) on device).
     """
     B, T, Lmax = batch.indices.shape
-    used = batch.used_per_table                     # (T,)
-    # partial transfer: ship only rows [0, used_t) of each table's slice,
-    # in a staging buffer padded to its width bucket
-    columns = int(used.sum())
-    staged = np.zeros((B, staged_width(columns, T * Lmax)), np.int32)
-    col = 0
-    for t in range(T):
-        u = int(used[t])
-        staged[:, col:col + u] = batch.indices[:, t, :u]
-        col += u
+    lengths = batch.lengths
+    if lengths.size and (lengths.min() < 0 or lengths.max() > Lmax):
+        raise ValueError(f"bag lengths must lie in [0, {Lmax}]")
+    # partial transfer: ship only the lookups inside each bag, item by
+    # item, in a staging buffer padded to its width bucket
+    staged = _item_major(batch.indices, lengths)
     if stats is not None:
-        live = B * columns * staged.itemsize
-        stats.bytes_full += batch.indices.nbytes + batch.lengths.nbytes
-        stats.bytes_partial += live + batch.lengths.nbytes
-        stats.bytes_padding += staged.nbytes - live
+        live = int(lengths.sum())
+        stats.bytes_full += batch.indices.nbytes + lengths.nbytes
+        stats.bytes_partial += live * staged.itemsize + lengths.nbytes
+        stats.bytes_padding += staged.nbytes - live * staged.itemsize
         stats.num_transfers_naive += T + 1          # one per table + lengths
         stats.num_transfers_batched += 2            # staged + lengths
         stats.rows_gathered += staged.size
-        stats.rows_live += int(batch.lengths.sum())
-    return jax.device_put(staged, device), jax.device_put(batch.lengths,
-                                                          device)
+        stats.rows_live += live
+    return jax.device_put(staged, device), jax.device_put(lengths, device)
 
 
 def command_batched_transfer(batch: SparseBatch,

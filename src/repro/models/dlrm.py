@@ -143,32 +143,29 @@ def _take_dequant(slab_or_q, idx):
 
 def _staged_columns(lengths, width: int):
     """The column map of a ``(B, width)`` staging buffer
-    (``core/transfer.py``: each table's used columns in table order, then
-    padding), from lengths (B, T): each column's table, (width,), and
-    whether the column holds a lookup inside its bag, (B, width)."""
-    used = lengths.max(axis=0)                                  # (T,)
-    end = jnp.cumsum(used)
+    (``core/transfer.py``: each item's bags back to back in table order,
+    then padding), from lengths (B, T): each column's table, (B, width),
+    and whether the column holds a lookup, (B, width)."""
+    end = jnp.cumsum(lengths, axis=1)                           # (B, T)
     col = jnp.arange(width)
-    table = jnp.minimum(jnp.searchsorted(end, col, side="right"),
-                        lengths.shape[1] - 1)
-    pos = col - (end - used)[table]     # padding: >= the last table's used
-    return table, pos[None, :] < lengths[:, table]
+    done = jnp.sum(end[:, :, None] <= col, axis=1)   # bags ended before col
+    return (jnp.minimum(done, lengths.shape[1] - 1),
+            col[None, :] < end[:, -1:])
 
 
 def _sls_staged(slab, offsets, staged, lengths):
     """staged (B, W) ids, lengths (B, T) -> pooled (B, T, D) float32:
     gather the B x W staged rows, zero the dead columns, and sum each
-    table's columns: one (T, W) one-hot contraction at float32 precision
-    (on a TPU v5e about 3 % faster than a segment sum over the columns)."""
+    item's columns table by table: a (T, W) one-hot contraction per item
+    at float32 precision."""
     T = lengths.shape[1]
     table, live = _staged_columns(lengths, staged.shape[1])
-    rows = _take_dequant(slab, staged + offsets[table][None, :])
+    rows = _take_dequant(slab, staged + offsets[table])
     rows = jnp.where(live[..., None], rows, 0.0).astype(jnp.float32)
-    B, W, D = rows.shape
-    onehot = (jnp.arange(T)[:, None] == table[None, :]).astype(jnp.float32)
-    pooled = jnp.dot(onehot, rows.transpose(1, 0, 2).reshape(W, B * D),
-                     precision=jax.lax.Precision.HIGHEST)
-    return pooled.reshape(T, B, D).transpose(1, 0, 2)
+    onehot = (jnp.arange(T)[None, :, None] == table[:, None, :]).astype(
+        jnp.float32)                                            # (B, T, W)
+    return jnp.einsum("btw,bwd->btd", onehot, rows,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def sls_forward(params, cfg: DLRMConfig, assignment: TableAssignment,

@@ -12,8 +12,9 @@ N+2's host ingest — the generalization of the paper's two-stage overlap.
 Compiled stages live in the shared StageExecutor; admission/latency/SLA
 accounting flows through the shared Scheduler + Telemetry.
 
-The ingest ships each request's ids as one ``(B, W)`` staging buffer
-(its tables' used columns; ``W`` a width bucket) and the sparse stage
+The ingest ships each request's ids as one ``(B, W)`` staging buffer,
+item-major (each item's bags back to back in table order, then zeros;
+``W`` the width bucket of the longest item), and the sparse stage
 gathers straight from it. The first request of each batch size compiles
 the sparse stage for every width bucket, so later requests compile nothing
 whatever their bag lengths. Profiler spans use the LM engine's names:

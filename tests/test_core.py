@@ -130,6 +130,26 @@ def test_partial_transfer_roundtrip(rng):
     assert stats.num_transfers_batched < stats.num_transfers_naive
 
 
+def test_live_share_counts_lookups_over_gathered_slots():
+    assert TR.TransferStats().live_share == 0.0
+    bags = [[[5, 6, 7], [8]], [[], [9, 10]]]       # items of 4 and 2 lookups
+    stats = TR.TransferStats()
+    staged, _ = TR.staged_transfer(
+        TR.pack_sparse_inputs(bags, num_tables=2, max_lookups=3), stats)
+    np.testing.assert_array_equal(np.asarray(staged),
+                                  [[5, 6, 7, 8], [9, 10, 0, 0]])
+    assert (stats.rows_live, stats.rows_gathered) == (6, 8)
+    assert stats.live_share == 0.75
+
+
+@pytest.mark.parametrize("bad", [4, -1])
+def test_staged_transfer_refuses_lengths_outside_the_bag_limit(bad):
+    sb = TR.pack_sparse_inputs([[[1, 2], [3]]], num_tables=2, max_lookups=3)
+    sb.lengths[0, 1] = bad
+    with pytest.raises(ValueError):
+        TR.staged_transfer(sb)
+
+
 def _one_lookup_bags(rng):
     bags = [[[1] for _ in range(16)] for _ in range(8)]   # 1 of 64 slots used
     return TR.pack_sparse_inputs(bags, num_tables=16, max_lookups=64)

@@ -182,14 +182,13 @@ def test_packed_scale_bias_reads_the_same_rows():
 # ---- ingest: the staged ids, one sparse compile per width bucket ---------
 
 def _staged(batch):
-    """Each table's used columns, in table order, then zeros to the width
-    bucket."""
+    """Each item's bags back to back in table order, then zeros to the
+    width bucket of the longest item."""
     B, T, L = batch.indices.shape
-    used = batch.used_per_table
-    cols = np.concatenate([batch.indices[:, t, :used[t]] for t in range(T)],
-                          axis=1)
-    return np.pad(cols, ((0, 0), (0, TR.staged_width(cols.shape[1], T * L)
-                                  - cols.shape[1])))
+    items = [np.concatenate([batch.indices[b, t, :batch.lengths[b, t]]
+                             for t in range(T)]) for b in range(B)]
+    W = TR.staged_width(max(len(i) for i in items), T * L)
+    return np.stack([np.pad(i, (0, W - len(i))) for i in items])
 
 
 def _bags(rng, B, T, L, rows):
@@ -226,17 +225,17 @@ def test_ingests_compile_nothing_after_the_first(model):
                 np.asarray(pooled),
                 np.asarray(D.sls_forward(eng.params, cfg, asn, idx, lens)),
                 rtol=1e-6, atol=1e-6)
-            used = int(sb.used_per_table.sum())
-            W = TR.staged_width(used, T * L)
+            live = int(lens.sum())
+            W = TR.staged_width(int(lens.sum(axis=1).max()), T * L)
             widths.add(W)
-            # the byte totals as the per-table unpack counted them
+            # live ids and lengths shipped, the rest of the buffer padding
             want.bytes_full += idx.nbytes + lens.nbytes
-            want.bytes_partial += B * used * 4 + lens.nbytes
-            want.bytes_padding += B * (W - used) * 4
+            want.bytes_partial += live * 4 + lens.nbytes
+            want.bytes_padding += (B * W - live) * 4
             want.num_transfers_naive += T + 1
             want.num_transfers_batched += 2
             want.rows_gathered += B * W
-            want.rows_live += int(lens.sum())
+            want.rows_live += live
     assert len(widths) > 2                  # the bags did change bucket
     assert per_request[0] > 0 and per_request[1:] == [0] * 19
     assert eng.transfer_stats == want

@@ -1,6 +1,6 @@
 """The sparse stage on the ingest's staging buffer: ``sls_forward`` on the
-``(B, W)`` staged ids (``core/transfer.py``: each table's used columns in
-table order, then padding) against the padded ``(B, T, L)`` bags, and
+``(B, W)`` staged ids (``core/transfer.py``: each item's bags back to back
+in table order, then padding) against the padded ``(B, T, L)`` bags, and
 ``DLRMEngine.serve`` against ``dlrm_forward`` on the padded bags."""
 import os
 import subprocess
@@ -80,29 +80,66 @@ def test_staged_matches_padded(smoke, kind, bags):
 
 @pytest.mark.parametrize("kind", ["float", "int8_sb"])
 def test_staged_padding_columns_are_not_read(smoke, kind):
-    """Ids in the staging buffer past the used columns, and past each bag
-    inside a table's columns, add nothing."""
+    """Ids in the staging buffer past each item's lookups add nothing."""
     cfg, asn, params, sls = smoke
     params = _slab(params, kind)
     rng = np.random.default_rng(1)
     B, T, L = 6, cfg.num_tables, cfg.max_lookups_per_table
     idx = np.zeros((B, T, L), np.int32)
     lens = np.ones((B, T), np.int32)
-    lens[:, :5] = [3, 1, 4, 1, 1]          # 13 used columns, width 16
+    lens[:, :5] = [3, 1, 4, 1, 1]          # item 0: 13 lookups, width 16
+    lens[1:, 0] = 0                        # the others shorter, each by
+    lens[2:, 2] = np.arange(B - 2)         # its own number of columns
     for t in range(T):
         idx[:, t] = rng.integers(0, cfg.table_rows[t], (B, L))
     idx[np.arange(L)[None, None] >= lens[..., None]] = 0
     staged, lens_dev = TR.staged_transfer(TR.SparseBatch(idx, lens))
     assert staged.shape == (B, 16)
     junk = np.asarray(staged).copy()
-    junk[:, 13:] = rng.integers(0, 100, (B, 3))
-    col = 0
-    for t, u in enumerate(lens.max(0)):
-        dead = np.arange(u)[None, :] >= lens[:, t, None]
-        junk[:, col:col + u][dead] = rng.integers(0, 100, int(dead.sum()))
-        col += u
+    dead = np.arange(16)[None, :] >= lens.sum(axis=1)[:, None]
+    assert dead[1:].any(axis=1).all()
+    junk[dead] = rng.integers(0, 100, int(dead.sum()))
     np.testing.assert_allclose(np.asarray(sls(params, junk, lens_dev)),
                                np.asarray(sls(params, idx, lens)), **TOL)
+
+
+def _ragged(rng, B, T, L):
+    """Seeded bags, some of length 0; item 0 fills its width bucket
+    exactly and is the longest."""
+    lens = rng.integers(0, L + 1, (B, T)).astype(np.int32)
+    lens[rng.random((B, T)) < 0.2] = 0
+    fill = TR.staged_width(int(lens.sum(axis=1).max()), T * L)
+    lens[0] = 0
+    for t in range(T):                      # item 0: `fill` lookups
+        lens[0, t] = min(L, fill - lens[0].sum())
+    assert lens[0].sum() == fill
+    idx = rng.integers(0, 50, (B, T, L)).astype(np.int32)
+    return idx, lens
+
+
+@pytest.mark.parametrize("B,T,L,seed", [(7, 5, 6, 0), (12, 9, 16, 1),
+                                         (4, 26, 100, 2)])
+def test_item_major_staging(B, T, L, seed):
+    rng = np.random.default_rng(seed)
+    idx, lens = _ragged(rng, B, T, L)
+    stats = TR.TransferStats()
+    staged, lens_dev = TR.staged_transfer(TR.SparseBatch(idx, lens), stats)
+    W = staged.shape[1]
+    assert W == TR.staged_width(int(lens.sum(axis=1).max()), T * L)
+    assert W == lens[0].sum()
+    table_major = int(lens.max(axis=0).sum())   # each table's longest bag
+    assert W <= TR.staged_width(table_major, T * L)
+    assert stats.rows_gathered == B * W and stats.rows_live == lens.sum()
+    padded = np.where(np.arange(L) < lens[..., None], idx, 0)
+    np.testing.assert_array_equal(
+        np.asarray(TR.unpack_indices(staged, lens_dev, L)), padded)
+    rows, dim = 50 * T, 16                  # rows drawn as init_dlrm does
+    slab = jax.random.normal(jax.random.PRNGKey(seed), (rows, dim)) / 4
+    offsets = jax.numpy.arange(0, rows, 50, dtype=np.int32)
+    got = D._sls_staged(slab, offsets, staged, lens_dev)
+    want = D._pool_rows(D._take_dequant(slab, padded + offsets[:, None]),
+                        lens, L)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
 
 
 def test_engine_serves_what_dlrm_forward_gives(smoke):
